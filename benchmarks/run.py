@@ -24,6 +24,7 @@ from benchmarks import (bench_ablation, bench_dist, bench_fixed_lstm,
                         bench_roofline, bench_serving, bench_tree_fc,
                         bench_tree_lstm, bench_var_lstm)
 from benchmarks.common import add_stage_rows, emit_pipeline_stages
+from repro.compile_cache import enable_compile_cache
 from repro.obs import trace
 from repro.obs.registry import fresh_registry
 
@@ -66,6 +67,7 @@ def main() -> None:
     ap.add_argument("--out-dir", default="results",
                     help="directory for BENCH_<suite>.json records")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("suite,name,value,unit,detail")
     failures = 0

@@ -14,9 +14,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.scheduler import execute, readout_nodes, readout_roots
 from repro.core.structure import chain, pack_batch, pack_external
 from repro.models.rnn import LSTMVertex
+
+enable_compile_cache()
 
 B, SRC_LEN, TGT_LEN, D, H = 4, 10, 7, 16, 24
 rng = np.random.default_rng(0)

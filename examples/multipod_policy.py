@@ -18,8 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.dist import compress, elastic, pipeline
 from repro.dist.sharding import ShardingPolicy, param_specs, policy_for_mesh
+
+enable_compile_cache()
 
 # --- 1. mesh + policy ------------------------------------------------------
 devs = np.asarray(jax.devices()).reshape(2, 2, 2)
@@ -50,10 +53,9 @@ print(f"gpipe over pod axis: loss={loss:.3f}, "
       f"bubble={pipeline.bubble_fraction(2, 4):.0%}")
 
 # --- 4. int8 + error-feedback cross-pod reduction ---------------------------
-from jax.experimental.shard_map import shard_map
 x = jnp.stack([jnp.full((8,), 1.0), jnp.full((8,), 3.0)])
-f = shard_map(lambda v: compress.cross_pod_mean_int8(v[0])[None],
-              mesh=pmesh, in_specs=P("pod"), out_specs=P("pod"))
+f = jax.shard_map(lambda v: compress.cross_pod_mean_int8(v[0])[None],
+                  mesh=pmesh, in_specs=P("pod"), out_specs=P("pod"))
 with pmesh:
     out = f(x)
 print("cross-pod int8 mean of (1, 3):", float(out[0, 0]),

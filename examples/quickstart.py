@@ -16,11 +16,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.scheduler import execute_lazy, readout_roots
 from repro.core.structure import random_binary_tree
 from repro.models.treelstm import TreeLSTMVertex
 from repro.obs import trace
 from repro.pipeline import SchedulePipeline
+
+enable_compile_cache()
 
 # --- 1. declare F once (the static vertex function) ----------------------
 fn = TreeLSTMVertex(input_dim=32, hidden=64, arity=2)

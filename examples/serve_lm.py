@@ -13,10 +13,13 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.archs import reduced
 from repro.models.transformer import TransformerLM
 from repro.serve import Request, ServeEngine
+
+enable_compile_cache()
 
 cfg = reduced(get_config("granite-3-8b"))
 lm = TransformerLM(cfg)

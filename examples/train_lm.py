@@ -13,6 +13,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.archs import reduced
 from repro.data import lm_batches, synthetic_corpus
@@ -22,6 +23,7 @@ from repro.train import MetricLogger, TrainConfig, Trainer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

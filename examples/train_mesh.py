@@ -16,12 +16,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.scheduler import execute, readout_roots
 from repro.core.structure import random_binary_tree
 from repro.dist.elastic import plan_downsize, remesh
 from repro.models.treelstm import TreeLSTMVertex
 from repro.pipeline import ShardedPipeline
 from repro.train import MetricLogger, TrainConfig, Trainer
+
+enable_compile_cache()
 
 IN_DIM, HIDDEN = 16, 8
 

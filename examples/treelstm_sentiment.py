@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.scheduler import execute_lazy, readout_roots
 from repro.data import ComposedBatchSource, sst_like_dataset
 from repro.models.treelstm import TreeLSTMVertex
@@ -34,6 +35,7 @@ from repro.pipeline import BucketPolicy, SchedulePipeline
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=150)
     ap.add_argument("--batch", type=int, default=16)

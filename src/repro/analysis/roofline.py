@@ -1,4 +1,4 @@
-"""Three-term roofline model for TPU v5e (the assignment's target chip).
+"""Three-term roofline model, with per-chip peaks keyed by device kind.
 
 Per compiled (arch × shape × mesh) step::
 
@@ -12,8 +12,8 @@ explicitly requested (``per_device=False``).  Collective bytes come from
 the HLO parse (``analysis.hlo``) and are per-device by the output-bytes
 convention documented there.
 
-Hardware constants (assignment-specified):
-  197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+The peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``;
+a kind missing from the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -26,15 +26,37 @@ from repro.analysis import hlo as hlo_mod
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    """TPU v5e per-chip constants."""
+    """Published per-chip constants."""
 
-    peak_flops: float = 197e12       # bf16 FLOP/s
-    hbm_bw: float = 819e9            # bytes/s
-    ici_bw: float = 50e9             # bytes/s per link
-    hbm_bytes: float = 16e9          # capacity, for fits-check commentary
+    peak_flops: float                # bf16 FLOP/s
+    hbm_bw: float                    # bytes/s
+    ici_bw: float                    # bytes/s per link
+    hbm_bytes: float                 # capacity, for fits-check commentary
 
 
-V5E = HW()
+#: Per-chip peaks by ``device_kind``.  TPU v5e (JAX reports it as
+#: "TPU v5 lite"), from the Google Cloud documentation, "TPU v5e":
+#: 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+#: chip-to-chip interconnect (200 GB/s over 4 links = 50 GB/s a link).
+PEAKS: Dict[str, HW] = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                      hbm_bytes=16e9),
+}
+
+#: The v5e entry (the dry-run's target chip).
+V5E_KIND = "TPU v5 lite"
+V5E = PEAKS[V5E_KIND]
+
+
+def peaks_for(device_kind: str) -> HW:
+    """The published peaks of ``device_kind``; raises on a kind the
+    table does not hold."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            f"them to roofline.PEAKS with their source") from None
 
 
 def model_flops(param_count: int, tokens: int, *,
@@ -56,20 +78,25 @@ class RooflineReport:
     collective_detail: Dict[str, int]
     model_flops_total: float          # 6·N·D for the global step
     peak_memory_bytes: float          # per device, from memory_analysis
+    device_kind: str                  # key into PEAKS
     bytes_detail: Dict[str, float] = dataclasses.field(default_factory=dict)
+
+    @property
+    def hw(self) -> HW:
+        return peaks_for(self.device_kind)
 
     # -- the three terms, in seconds -------------------------------------
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / V5E.peak_flops
+        return self.hlo_flops / self.hw.peak_flops
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / V5E.hbm_bw
+        return self.hlo_bytes / self.hw.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / V5E.ici_bw
+        return self.collective_bytes / self.hw.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -91,7 +118,7 @@ class RooflineReport:
     @property
     def mfu_bound(self) -> float:
         """Model-FLOPs utilization at the roofline bound (the score)."""
-        denom = self.t_bound * self.chips * V5E.peak_flops
+        denom = self.t_bound * self.chips * self.hw.peak_flops
         return self.model_flops_total / denom if denom else 0.0
 
     def row(self) -> Dict[str, Any]:
@@ -113,6 +140,7 @@ class RooflineReport:
 
 def roofline_report(*, arch: str, shape: str, mesh_name: str, chips: int,
                     hlo_text: str, model_flops_total: float,
+                    device_kind: str,
                     peak_memory_bytes: float = 0.0,
                     arch_cfg=None, shape_cfg=None,
                     n_micro: int = 1) -> RooflineReport:
@@ -146,4 +174,5 @@ def roofline_report(*, arch: str, shape: str, mesh_name: str, chips: int,
         collective_detail={k: int(v) for k, v in c.collectives.items()},
         model_flops_total=model_flops_total,
         peak_memory_bytes=peak_memory_bytes,
+        device_kind=device_kind,
         bytes_detail=adj_detail)

@@ -184,10 +184,12 @@ def resolve_fusion(fn: VertexFunction, fusion_mode: str = "auto", *,
 def _megastep_scan(spec: GateSpec, weights, sched: DeviceSchedule,
                    ext: Array, dtype) -> Array:
     """Forward scan where each batching task is ONE fused megastep: the
-    buffer is carried (and, on the pallas backend, aliased) in place."""
+    buffer is carried (and, on the pallas backend, aliased) in place.
+    Takes ``ext`` and returns the buffer in the row layout
+    ``[·, 1, ·]`` the kernels DMA rows of (``megastep.as_rows``)."""
     T, M = sched.T, sched.M
     S = spec.state_dim
-    buf0 = jnp.zeros((T * M + 1, S), dtype)
+    buf0 = jnp.zeros((T * M + 1, 1, S), dtype)
 
     def step(buf, xs):
         t, child_ids, child_mask, ext_ids, node_mask = xs
@@ -208,15 +210,17 @@ def _execute_megastep(fn: VertexFunction, params: Params, external: Array,
     Returns the ``[T*M + 1, S]`` buffer; hoisting is always on."""
     spec = get_gate_spec(fn)
     ext = fn.project_inputs(params, external)
-    return _megastep_scan(spec, spec.weights(params), sched, ext, ext.dtype)
+    return megastep.from_rows(_megastep_scan(
+        spec, spec.weights(params), sched, megastep.as_rows(ext), ext.dtype))
 
 
 def _megastep_fwd(fn, params, external, sched):
     ext, hoist_vjp = jax.vjp(
         lambda p, e: fn.project_inputs(p, e), params, external)
     spec = get_gate_spec(fn)
-    buf = _megastep_scan(spec, spec.weights(params), sched, ext, ext.dtype)
-    return buf, (params, ext, buf, sched, hoist_vjp)
+    buf = _megastep_scan(spec, spec.weights(params), sched,
+                         megastep.as_rows(ext), ext.dtype)
+    return megastep.from_rows(buf), (params, ext, buf, sched, hoist_vjp)
 
 
 def _megastep_bwd(fn, res, g_buf):
@@ -224,13 +228,14 @@ def _megastep_bwd(fn, res, g_buf):
     (recompute + cotangent math + ∂gather scatter-add fused,
     ``kops.bwd_megastep`` — §3.4) + ONE flat lazily-batched
     parameter/external gradient pass (§3.5).  Activations are
-    recomputed from the saved node buffer (remat)."""
+    recomputed from the saved node buffer (remat).  The residual
+    buffer and the swept gradient buffer stay in the row layout."""
     params, ext, buf, sched, hoist_vjp = res
     spec = get_gate_spec(fn)
     weights = spec.weights(params)
     T, M, A = sched.T, sched.M, sched.A
     S = spec.state_dim
-    g_buf = g_buf.astype(jnp.float32)
+    ext_rows = megastep.as_rows(ext)
 
     # Sorted-run arrays travel with the schedule (precomputed host-side
     # in pack_batch) so the reverse scan body contains NO sort op; a
@@ -248,7 +253,7 @@ def _megastep_bwd(fn, res, g_buf):
         # single launch mirroring the forward; off-pallas the jnp
         # ``level_bwd`` sweep — the correctness oracle).
         g = kops.bwd_megastep(spec.kind, g, buf, child_ids, child_mask,
-                              ext_ids, node_mask, t * M, ext, weights,
+                              ext_ids, node_mask, t * M, ext_rows, weights,
                               sort_perm=sp, sorted_child_ids=sc, run_head=rh)
         return g, None
 
@@ -256,11 +261,13 @@ def _megastep_bwd(fn, res, g_buf):
           sched.ext_ids, sched.node_mask)
     if have_runs:
         xs = xs + (sched.sort_perm, sched.sorted_child_ids, sched.run_head)
-    g_final, _ = jax.lax.scan(rev_step, g_buf, xs, reverse=True)
+    g_final, _ = jax.lax.scan(
+        rev_step, megastep.as_rows(g_buf.astype(jnp.float32)), xs,
+        reverse=True)
     # Row t*M+m reaches its final value before level t's reverse step
     # runs (all its parents live at levels > t), so the swept buffer IS
     # the per-slot state cotangent — no per-level stacking needed.
-    g_state_flat = g_final[: T * M] \
+    g_state_flat = megastep.from_rows(g_final)[: T * M] \
         * sched.node_mask.reshape(T * M)[:, None].astype(g_final.dtype)
 
     # Lazy batching: one analytic pass over ALL T*M slots for the
@@ -494,8 +501,10 @@ def frontier_step(fn: VertexFunction, params: Params, buf: Array,
     property, extended across requests).
 
     With ``spec`` the row math routes through the fused frontier
-    megastep (``kops.frontier_megastep``); without it the op-by-op
-    gather → apply → scatter.  Both legs compute bit-identical rows to
+    megastep (``kops.frontier_megastep``) and ``buf`` is in the row
+    layout ``[N, 1, S]`` (``megastep.as_rows`` — the caller keeps it
+    across its tick scan); without it the op-by-op gather → apply →
+    scatter on ``[N, S]``.  Both legs compute bit-identical rows to
     what :func:`execute` computes for the same vertex on the matching
     leg, which is what lets the engine prove per-request bit-identity
     against solo scoring.

@@ -16,7 +16,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -61,9 +60,9 @@ def gpipe_spmd(stage_fn: Callable[[Any, jax.Array], jax.Array],
             return jax.lax.psum(
                 jnp.where(idx == n - 1, outs, jnp.zeros_like(outs)), axis)
 
-        out = shard_map(body, mesh=mesh,
-                        in_specs=(P(axis), P()), out_specs=P(),
-                        check_rep=False)(stacked, xs)
+        out = jax.shard_map(body, mesh=mesh,
+                            in_specs=(P(axis), P()), out_specs=P(),
+                            check_vma=False)(stacked, xs)
         return loss_fn(out) if loss_fn is not None else out
 
     return run

@@ -2,14 +2,15 @@
 
 Cavs implements ``gather``/``scatter``/``pull``/``push`` as one
 customized ``memcpy`` kernel that moves many slices in a single launch.
-The TPU rendering: a Pallas kernel whose *grid index map is driven by
-scalar-prefetched indices* — row ``i`` of the output block-maps to row
-``idx[i]`` of the source, so the DMA engine streams whole ``[1, D]``
-rows HBM→VMEM→HBM with zero gather arithmetic in the vector units.
+The TPU rendering: scalar-prefetched indices address one DMA per row,
+so whole rows move HBM→VMEM→HBM with zero gather arithmetic in the
+vector units.  Arrays are in the row layout ``[R, 1, D]``
+(``level_megastep.as_rows``), where any single row is a legal DMA.
 
-``gather_rows``  : out[i, :] = src[idx[i], :]
-``scatter_rows`` : dst[idx[i], :] = rows[i, :]   (unique indices; dst is
-                   aliased in-place, untouched rows preserved)
+``gather_rows``  : out[i] = src[idx[i]]
+``scatter_rows`` : dst[idx[i]] = rows[i]   (unique indices; dst is
+                   aliased in place, untouched rows preserved,
+                   out-of-range indices dropped)
 """
 
 from __future__ import annotations
@@ -21,78 +22,72 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
+from repro.kernels import level_megastep as lm
 
 
-def _copy_kernel(idx_ref, src_ref, out_ref):
-    del idx_ref  # consumed by the index maps
-    out_ref[...] = src_ref[...]
+def _gather_kernel(idx_ref, src_ref, out_ref, sem, *, bn: int):
+    i0 = pl.program_id(0) * bn
+    lm.start_row_copies(src_ref, out_ref, sem.at[0], bn,
+                        lambda r: idx_ref[i0 + r])
+    lm.wait_row_copies(src_ref, out_ref, sem.at[0], bn)
 
 
-def _scatter_kernel(idx_ref, dst_ref, rows_ref, out_ref):
-    del idx_ref, dst_ref  # dst rides along only for the alias
-    out_ref[...] = rows_ref[...]
-
-
-def gather_rows(src: jax.Array, idx: jax.Array, *, block_d: int = 512,
-                rows_per_block: int = 8,
+def gather_rows(src: jax.Array, idx: jax.Array, *,
                 interpret: bool = False) -> jax.Array:
-    """``src``: ``[R, D]``; ``idx``: ``[n]`` int32 in ``[0, R)`` →
-    ``[n, D]``.
-
-    Rows are fetched ``rows_per_block`` at a time; within a block the
-    index map selects each source row independently via scalar prefetch
-    (``idx`` lives in SMEM before the grid starts).
-    """
-    R, D = src.shape
+    """``src``: ``[R, 1, D]``; ``idx``: ``[n]`` int32 in ``[0, R)`` →
+    ``[n, 1, D]``, ``block_rows(n)`` rows per grid step."""
     n = idx.shape[0]
-    bd = min(block_d, _round_up(D, 128))
-    Dp = _round_up(D, bd)
-    srcp = jnp.pad(src, ((0, 0), (0, Dp - D)))
-
+    D = src.shape[-1]
+    bn = lm.block_rows(n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n, Dp // bd),
-        in_specs=[pl.BlockSpec((1, bd), lambda i, j, idx_ref: (idx_ref[i], j))],
-        out_specs=pl.BlockSpec((1, bd), lambda i, j, idx_ref: (i, j)),
+        grid=(n // bn,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((bn, 1, D), lambda i, idx_ref: (i, 0, 0)),
+        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
     )
-    out = pl.pallas_call(
-        _copy_kernel,
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, bn=bn),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, Dp), src.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, D), src.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), srcp)
-    return out[:, :D]
+    )(idx.astype(jnp.int32), src)
+
+
+def _scatter_kernel(idx_ref, dst_ref, rows_ref, out_ref, sem, *,
+                    bn: int, R: int):
+    del dst_ref  # rides along only for the alias
+    i0 = pl.program_id(0) * bn
+
+    @pl.loop(0, bn)
+    def _row(r):
+        d = idx_ref[i0 + r]
+
+        @pl.when(jnp.logical_and(d >= 0, d < R))
+        def _write():
+            lm.copy_now(rows_ref.at[r], out_ref.at[d], sem.at[0])
 
 
 def scatter_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array, *,
-                 block_d: int = 512, interpret: bool = False) -> jax.Array:
-    """``dst``: ``[R, D]``; ``idx``: ``[n]`` unique int32; ``rows``:
-    ``[n, D]`` → updated ``[R, D]`` (functional; dst buffer aliased)."""
-    R, D = dst.shape
+                 interpret: bool = False) -> jax.Array:
+    """``dst``: ``[R, 1, D]``; ``idx``: ``[n]`` unique int32; ``rows``:
+    ``[n, 1, D]`` → updated ``[R, 1, D]`` (functional; dst buffer
+    aliased).  Indices outside ``[0, R)`` are dropped."""
+    R, _, D = dst.shape
     n = idx.shape[0]
-    bd = min(block_d, _round_up(D, 128))
-    Dp = _round_up(D, bd)
-    dstp = jnp.pad(dst, ((0, 0), (0, Dp - D)))
-    rowsp = jnp.pad(rows, ((0, 0), (0, Dp - D)))
-
-    sink = pl.BlockSpec((1, bd), lambda i, j, idx_ref: (idx_ref[i], j))
+    bn = lm.block_rows(n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n, Dp // bd),
-        in_specs=[
-            sink,                                                # dst (alias)
-            pl.BlockSpec((1, bd), lambda i, j, idx_ref: (i, j)),  # rows
-        ],
-        out_specs=sink,
+        grid=(n // bn,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),              # dst
+                  pl.BlockSpec((bn, 1, D), lambda i, idx_ref: (i, 0, 0))],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
     )
-    out = pl.pallas_call(
-        _scatter_kernel,
+    return pl.pallas_call(
+        functools.partial(_scatter_kernel, bn=bn, R=R),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, Dp), dst.dtype),
+        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
         input_output_aliases={1: 0},   # dst (first tensor operand) → out
         interpret=interpret,
-    )(idx.astype(jnp.int32), dstp, rowsp)
-    return out[:, :D]
+    )(idx.astype(jnp.int32), dst, rows.astype(dst.dtype))

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.kernels import cell_kernels, decode_attention as dec
 from repro.kernels import flash_attention as fa
 from repro.kernels import gather_scatter as gsc
+from repro.kernels import level_megastep as lm
 from repro.kernels import mamba_ssd as ssd
 from repro.kernels import ref
 from repro.obs.registry import get_registry
@@ -96,38 +97,22 @@ def level_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
     the declared gate math VMEM-resident, block-write rows
     ``[offset, offset+M)`` in place (kernels/level_megastep.py).
 
-    ``kind``/``weights`` come from the cell's ``GateSpec``.  The pallas
-    backend is a single launch with the buffer aliased input→output;
-    the fallback is the op-by-op oracle in ``ref.py`` (same math, same
-    contiguous-block write, no fusion guarantee).
+    ``buf`` (``[T*M+1, 1, S]``) and ``ext`` (``[E, 1, G]``) are in the
+    row layout (``level_megastep.as_rows``); callers keep it across the
+    level scan.  ``kind``/``weights`` come from the cell's
+    ``GateSpec``.  The pallas backend is a single launch with the
+    buffer aliased input→output; the fallback is the op-by-op oracle in
+    ``ref.py`` (same math, same contiguous-block write, no fusion
+    guarantee).
     """
     impl = _default_impl() if impl == "auto" else impl
     _tick("level_megastep", impl)
     if impl == "pallas":
-        from repro.kernels import level_megastep as lm
-        if kind == "lstm":
-            wh, b = weights
-            return lm.lstm_megastep(buf, child_ids, ext_ids, node_mask,
-                                    offset, ext, wh, b,
-                                    interpret=_interpret())
-        if kind == "treelstm":
-            ui, uf, uo, uu, b = weights
-            return lm.treelstm_megastep(buf, child_ids, ext_ids, node_mask,
-                                        offset, ext, ui, uf, uo, uu, b,
-                                        interpret=_interpret())
-        if kind == "gru":
-            wh, b = weights
-            return lm.gru_megastep(buf, child_ids, ext_ids, node_mask,
-                                   offset, ext, wh, b,
-                                   interpret=_interpret())
-        if kind == "treefc":
-            wc, b = weights
-            return lm.treefc_megastep(buf, child_ids, ext_ids, node_mask,
-                                      offset, ext, wc, b,
-                                      interpret=_interpret())
-        raise ValueError(f"unknown megastep gate kind: {kind!r}")
-    return ref.level_megastep(kind, buf, child_ids, child_mask, ext_ids,
-                              node_mask, offset, ext, weights)
+        return lm.megastep(kind, buf, child_ids, ext_ids, node_mask, offset,
+                           ext, weights, interpret=_interpret())
+    return lm.as_rows(ref.level_megastep(
+        kind, lm.from_rows(buf), child_ids, child_mask, ext_ids, node_mask,
+        offset, lm.from_rows(ext), weights))
 
 
 def frontier_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
@@ -136,12 +121,13 @@ def frontier_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
                       weights: Tuple[jax.Array, ...],
                       impl: str = "auto") -> jax.Array:
     """One batching task over a mixed-depth UNION frontier (continuous
-    serving): gather child rows from arbitrary arena rows of ``buf``,
-    run the declared gate math, scatter the masked states to the
-    per-row destinations ``out_ids`` (unique; out-of-range = pad lane,
-    dropped).  ``rows`` are the pre-gathered (eagerly projected) pulled
-    rows ``[M, G]`` — per-request level offsets are resolved host-side
-    by the engine, so the compiled program never changes.
+    serving): gather child rows from arbitrary arena rows of ``buf``
+    (``[N, 1, S]``, row layout), run the declared gate math, scatter
+    the masked states to the per-row destinations ``out_ids`` (unique;
+    out-of-range = pad lane, dropped).  ``rows`` are the pre-gathered
+    (eagerly projected) pulled rows ``[M, G]`` — per-request level
+    offsets are resolved host-side by the engine, so the compiled
+    program never changes.
 
     The pallas backend composes the two validated launches: the level
     megastep computes the frontier's states into a contiguous staging
@@ -155,18 +141,18 @@ def frontier_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
     _tick("frontier_megastep", impl)
     if impl == "pallas":
         M = child_ids.shape[0]
-        S = buf.shape[1]
         ncap = buf.shape[0]
-        staged = jnp.concatenate([buf, jnp.zeros((M, S), buf.dtype)], axis=0)
-        ext_ids = jnp.arange(M, dtype=jnp.int32)
-        staged = level_megastep(kind, staged, child_ids, child_mask,
-                                ext_ids, node_mask, ncap, rows, weights,
-                                impl="pallas")
-        states = jax.lax.dynamic_slice(staged, (ncap, 0), (M, S))
-        return gsc.scatter_rows(buf, out_ids, states,
+        staged = jnp.concatenate(
+            [buf, jnp.zeros((M,) + buf.shape[1:], buf.dtype)], axis=0)
+        staged = lm.megastep(kind, staged, child_ids,
+                             jnp.arange(M, dtype=jnp.int32), node_mask,
+                             jnp.int32(ncap), lm.as_rows(rows), weights,
+                             interpret=_interpret())
+        return gsc.scatter_rows(buf, out_ids, staged[ncap:],
                                 interpret=_interpret())
-    return ref.frontier_megastep(kind, buf, child_ids, child_mask, rows,
-                                 node_mask, out_ids, weights)
+    return lm.as_rows(ref.frontier_megastep(
+        kind, lm.from_rows(buf), child_ids, child_mask, rows, node_mask,
+        out_ids, weights))
 
 
 def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
@@ -183,7 +169,8 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
     declared gate kind, and scatter-ADD the child-row cotangents into
     the gradient buffer ``g`` (∂gather = scatter-add, §3.4) — in ONE
     launch on the pallas backend (``kernels/level_megastep_bwd.py``,
-    grad buffer aliased in place).
+    grad buffer aliased in place).  ``g``, ``buf`` and ``ext`` are in
+    the row layout, as for :func:`level_megastep`.
 
     The ``chunked`` fallback is the pre-fusion oracle sweep: the
     analytic jnp ``level_megastep.level_bwd`` sandwiched between the
@@ -205,10 +192,11 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
                                 sort_perm=sort_perm,
                                 sorted_child_ids=sorted_child_ids,
                                 run_head=run_head, interpret=_interpret())
+    g, buf, ext = lm.from_rows(g), lm.from_rows(buf), lm.from_rows(ext)
     if impl == "ref":
-        return ref.bwd_megastep(kind, g, buf, child_ids, child_mask, ext_ids,
-                                node_mask, offset, ext, weights)
-    from repro.kernels import level_megastep as lm
+        return lm.as_rows(ref.bwd_megastep(kind, g, buf, child_ids,
+                                           child_mask, ext_ids, node_mask,
+                                           offset, ext, weights))
     M, A = child_ids.shape
     S = g.shape[1]
     g_state = jax.lax.dynamic_slice(g, (offset, 0), (M, S)) \
@@ -217,16 +205,17 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
     rows = jnp.take(ext, ext_ids, axis=0)
     g_child, _, _ = lm.level_bwd(kind, g_state, child, rows, child_mask,
                                  weights)
-    return ref.scatter_add_rows(g, child_ids.reshape(-1),
-                                g_child.reshape(M * A, S).astype(g.dtype))
+    return lm.as_rows(ref.scatter_add_rows(
+        g, child_ids.reshape(-1), g_child.reshape(M * A, S).astype(g.dtype)))
 
 
 def scatter_add_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array,
                      impl: str = "auto") -> jax.Array:
     """``dst[idx[i]] += rows[i]`` with repeats — ∂gather = scatter-add
     (§3.4), the megastep reverse sweep's memory op.  The pallas backend
-    (kernels/level_megastep_bwd.py) is a column-striped accumulate with
-    the dst buffer aliased in place; the fallback is XLA's scatter-add.
+    (kernels/level_megastep_bwd.py) walks the contributions in
+    sorted-run order with the dst buffer aliased in place; the fallback
+    is XLA's scatter-add.
     """
     impl = _default_impl() if impl == "auto" else impl
     _tick("scatter_add_rows", impl)
@@ -244,7 +233,8 @@ def gather_rows(src: jax.Array, idx: jax.Array, impl: str = "auto") -> jax.Array
     impl = _default_impl() if impl == "auto" else impl
     _tick("gather_rows", impl)
     if impl == "pallas":
-        return gsc.gather_rows(src, idx, interpret=_interpret())
+        return lm.from_rows(gsc.gather_rows(lm.as_rows(src), idx,
+                                            interpret=_interpret()))
     return ref.gather_rows(src, idx)
 
 
@@ -253,7 +243,8 @@ def scatter_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array,
     impl = _default_impl() if impl == "auto" else impl
     _tick("scatter_rows", impl)
     if impl == "pallas":
-        return gsc.scatter_rows(dst, idx, rows, interpret=_interpret())
+        return lm.from_rows(gsc.scatter_rows(
+            lm.as_rows(dst), idx, lm.as_rows(rows), interpret=_interpret()))
     return ref.scatter_rows(dst, idx, rows)
 
 

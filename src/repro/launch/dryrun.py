@@ -41,7 +41,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis import hlo as hlo_mod
-from repro.analysis.roofline import model_flops, roofline_report
+from repro.analysis.roofline import V5E_KIND, model_flops, roofline_report
 from repro.configs import get_config
 from repro.configs.archs import ASSIGNED
 from repro.configs.base import ArchConfig
@@ -248,6 +248,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         rep = roofline_report(
             arch=arch, shape=shape_name, mesh_name=mname, chips=mesh.size,
             hlo_text=hlo_text, model_flops_total=mf,
+            device_kind=V5E_KIND,
             peak_memory_bytes=float(peak_mem + arg_mem + out_mem - alias_mem),
             arch_cfg=cfg, shape_cfg=shape, n_micro=n_micro)
         row.update(rep.row())

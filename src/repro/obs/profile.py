@@ -30,10 +30,12 @@ def walk_jaxpr(jx, scans: List[int], outside: List[int]) -> None:
     """Collect (pallas_call count inside each scan body) and the count
     outside any scan, recursing through nested jaxprs.  ``scans`` grows
     one entry per scan encountered; ``outside`` is a 1-element
-    accumulator."""
+    accumulator.  A kernel's own body is not walked: its in-kernel
+    loops are not launches."""
     for eqn in jx.eqns:
         if eqn.primitive.name == "pallas_call":
             outside[0] += 1
+            continue
         if eqn.primitive.name == "scan":
             body = eqn.params["jaxpr"].jaxpr
             inner_scans, inner = [], [0]

@@ -67,6 +67,7 @@ from repro.core.scheduler import frontier_step, resolve_fusion
 from repro.core.structure import InputGraph, LevelSchedule
 from repro.core.vertex import has_eager_projection
 from repro.dist.fault import chaos_corrupt_ext, chaos_fire
+from repro.kernels.level_megastep import as_rows, from_rows
 from repro.models.readout import ClassificationHead, TokenReadout
 from repro.obs import trace
 from repro.pipeline import BucketPolicy, ScheduleCache
@@ -189,16 +190,20 @@ def _frontier_window(fn, spec, params: Params, buf: jax.Array,
                      ext_rows: jax.Array, node_mask: jax.Array,
                      out_ids: jax.Array) -> jax.Array:
     """``k`` union-frontier ticks as one ``lax.scan`` (jitted once per
-    window length; occupancy, depths and destinations are all data)."""
+    window length; occupancy, depths and destinations are all data).
+    The fused leg carries the arena in the kernels' row layout for the
+    whole window, so it is relaid out once per window, not per tick."""
 
     def body(b, xs):
         cid, cm, er, nm, oid = xs
         return frontier_step(fn, params, b, cid, cm, er, nm, oid,
                              spec=spec), None
 
+    if spec is not None:
+        buf = as_rows(buf)
     buf, _ = jax.lax.scan(body, buf, (child_ids, child_mask, ext_rows,
                                       node_mask, out_ids))
-    return buf
+    return from_rows(buf) if spec is not None else buf
 
 
 class ContinuousBatchEngine(_EngineBase):
@@ -554,9 +559,9 @@ class ContinuousBatchEngine(_EngineBase):
                 out.block_until_ready()  # surface async kernel failures
                 self._breaker.record_success()
                 return out
-            except Exception:            # noqa: BLE001 — degrade
+            except Exception as e:       # noqa: BLE001 — degrade
                 self._breaker.record_failure()
-                self.lifecycle.degradations += 1
+                self.lifecycle.degrade(e)
         return self._window_oracle(*args)
 
     # -- retirement -----------------------------------------------------------

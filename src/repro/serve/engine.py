@@ -69,6 +69,7 @@ from repro.core.structure import InputGraph
 from repro.core.vertex import VertexIO
 from repro.dist.fault import chaos_fire
 from repro.kernels import ops as kops
+from repro.kernels.level_megastep import as_rows, from_rows
 from repro.obs import trace
 from repro.obs.registry import get_registry
 from repro.pipeline import (BucketPolicy, SchedulePipeline,
@@ -508,9 +509,9 @@ class VertexServeEngine(_EngineBase):
                 out.block_until_ready()  # surface async kernel failures
                 self._breaker.record_success()
                 return out
-            except Exception:            # noqa: BLE001 — degrade
+            except Exception as e:       # noqa: BLE001 — degrade
                 self._breaker.record_failure()
-                self.lifecycle.degradations += 1
+                self.lifecycle.degrade(e)
         return self._tick_oracle(*args)
 
     def run(self, max_ticks: int = 100_000) -> List[VertexRequest]:
@@ -709,9 +710,9 @@ class StructureServeEngine(_EngineBase):
                 out.block_until_ready()  # surface async kernel failures
                 self._breaker.record_success()
                 return out
-            except Exception:            # noqa: BLE001 — degrade
+            except Exception as e:       # noqa: BLE001 — degrade
                 self._breaker.record_failure()
-                self.lifecycle.degradations += 1
+                self.lifecycle.degrade(e)
         return self._run_oracle(self.params, dev, ext)
 
     def _health_extra(self) -> Dict[str, Any]:
@@ -739,9 +740,10 @@ def _vertex_tick(fn, spec, params: Params, buf: jax.Array,
     # rows, built host-side) — no ext sentinel needed on this path.
     ext_ids = jnp.arange(M, dtype=jnp.int32)
     if spec is not None:
-        return kops.level_megastep(spec.kind, buf, child_ids, child_mask,
-                                   ext_ids, node_mask, offset, ext,
-                                   spec.weights(params))
+        # The slot pool is 2M+1 rows: relaying it out per tick is cheap.
+        return from_rows(kops.level_megastep(
+            spec.kind, as_rows(buf), child_ids, child_mask, ext_ids,
+            node_mask, offset, as_rows(ext), spec.weights(params)))
     S = buf.shape[1]
     ch = jnp.take(buf, child_ids.reshape(-1), axis=0).reshape(M, 1, S)
     io = VertexIO(child_states=ch, child_mask=child_mask.astype(buf.dtype),

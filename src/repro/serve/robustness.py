@@ -98,6 +98,10 @@ class RequestLifecycle:
         self.failures = 0
         self.completed = 0
         self.degradations = 0             # fused → oracle fallbacks
+        #: ``"ExcType: message"`` of the exception behind the latest
+        #: degradation (None until one happens) — why the fused path
+        #: was left.
+        self.last_degradation: Optional[str] = None
         self.quarantines = 0              # batches that entered bisection
 
     # -- admission --------------------------------------------------------
@@ -176,6 +180,11 @@ class RequestLifecycle:
                      "deadline exceeded (ttl=%.6gs)" % req.ttl)
         self.timeouts += 1
 
+    def degrade(self, exc: BaseException) -> None:
+        """Count one fused → oracle fallback and keep its cause."""
+        self.degradations += 1
+        self.last_degradation = f"{type(exc).__name__}: {exc}"
+
     # -- health -----------------------------------------------------------
     def oldest_wait(self) -> float:
         """Seconds the oldest queued request has been waiting (0.0 when
@@ -195,6 +204,7 @@ class RequestLifecycle:
             "failed": self.failures,
             "deadline_misses": self.timeouts,
             "degradations": self.degradations,
+            "last_degradation": self.last_degradation,
             "quarantines": self.quarantines,
         }
         h.update(extra)

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import collections
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -74,6 +74,11 @@ class MetricLogger:
         inter-call gap includes it)."""
         self._train_times.append(float(sec))
         self._registry.observe("train.train_sec_per_step", float(sec))
+
+    @property
+    def train_times(self) -> List[float]:
+        """The window's per-step train seconds, oldest first."""
+        return list(self._train_times)
 
     def step(self, step: int, metrics: Dict[str, Any]) -> Dict[str, float]:
         now = time.perf_counter()

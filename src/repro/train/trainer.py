@@ -318,8 +318,6 @@ class Trainer:
         with the batch)."""
         import functools as _ft
 
-        from jax.experimental.shard_map import shard_map
-
         from repro.dist.compress import cross_pod_mean_int8_ef_tree
 
         cfg = self.cfg
@@ -330,8 +328,9 @@ class Trainer:
         def replica_step(params, ef, batch):
             # Everything here is per-replica: leaves arrive with a
             # leading [1] shard axis; outputs return one too (out_specs
-            # P(axis) reassembles them — check_rep stays off because a
-            # DeviceSchedule pytree is opaque to the rep checker).
+            # P(axis) reassembles them — check_vma stays off because a
+            # DeviceSchedule pytree is opaque to the varying-axes
+            # checker).
             local = jax.tree.map(lambda a: a[0], batch)
 
             def objective(p):
@@ -360,10 +359,10 @@ class Trainer:
                     new_ef, metrics)
 
         sharded = _ft.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(), P(axis), P(axis)),
             out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
-            check_rep=False)(replica_step)
+            check_vma=False)(replica_step)
 
         def step_fn(state: TrainState, batch: Batch
                     ) -> Tuple[TrainState, Dict[str, jax.Array]]:

@@ -236,12 +236,26 @@ def test_kernel_chaos_degrades_then_breaker_pins_oracle(tree_fn):
     assert not eng.fused
     h = eng.health()
     assert h["degradations"] == 2
+    assert h["last_degradation"].startswith("SimulatedFailure")
     assert h["breaker_open"] and h["breaker_trips"] == 1
     assert h["completed"] == 8 and h["failed"] == 0
     got = _roots_by_id(eng)
     for rid in want:
         np.testing.assert_allclose(got[rid], want[rid],
                                    rtol=1e-5, atol=1e-6)
+
+
+def test_lifecycle_keeps_last_degradation_cause():
+    """``health()`` names the exception behind the latest fused → oracle
+    fallback, so an operator (or the chip smoke test) can say why the
+    fused path was left."""
+    lc = RequestLifecycle()
+    assert lc.health()["last_degradation"] is None
+    lc.degrade(ValueError("first"))
+    lc.degrade(RuntimeError("Mosaic refused the kernel"))
+    h = lc.health()
+    assert h["degradations"] == 2
+    assert h["last_degradation"] == "RuntimeError: Mosaic refused the kernel"
 
 
 def test_vertex_engine_kernel_chaos_transient_recovery():

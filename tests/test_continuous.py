@@ -260,6 +260,7 @@ def test_degradation_ladder_and_double_failure():
     eng._window = orig
     assert r.status == "ok"               # the oracle rung finished it
     assert eng.health()["degradations"] > 0
+    assert eng.health()["last_degradation"] == "RuntimeError: kaboom"
     np.testing.assert_array_equal(
         r.root_state, _solo_root(fn, params, r.graph, r.inputs, "none"))
 

@@ -201,12 +201,11 @@ def test_cross_pod_mean_int8():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.dist.compress import cross_pod_mean_int8
 mesh = Mesh(np.asarray(jax.devices()).reshape(2), ("pod",))
 x = jnp.stack([jnp.full((8,), 1.0), jnp.full((8,), 3.0)])
-f = shard_map(lambda v: cross_pod_mean_int8(v[0], axis_name="pod")[None],
-              mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
+f = jax.shard_map(lambda v: cross_pod_mean_int8(v[0], axis_name="pod")[None],
+                  mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
 with mesh:
     out = f(x)
 np.testing.assert_allclose(np.asarray(out), 2.0, rtol=0.02)

@@ -31,7 +31,6 @@ _PRELUDE = """
 import os, numpy as np, jax, jax.numpy as jnp
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core.scheduler import execute, readout_roots
 from repro.core.structure import random_binary_tree
 from repro.dist.elastic import plan_downsize, remesh
@@ -130,8 +129,8 @@ def local_sum_and_per(p, local):
     per = per_sample(p, local["dev"], local["ext"], local["target"])
     return jnp.sum(per * local["weights"]), per
 
-@partial(shard_map, mesh=mesh, in_specs=(P(), P("data")),
-         out_specs=(P("data"), P("data")), check_rep=False)
+@partial(jax.shard_map, mesh=mesh, in_specs=(P(), P("data")),
+         out_specs=(P("data"), P("data")), check_vma=False)
 def per_replica(p, b):
     local = jax.tree.map(lambda a: a[0], b)
     (s, per), g = jax.value_and_grad(

@@ -17,6 +17,7 @@ from repro.core.structure import chain, pack_batch, pack_external, random_dag
 from repro.core.vertex import has_eager_projection
 from repro.kernels import ops as kops
 from repro.kernels import ref
+from repro.kernels.level_megastep import as_rows, from_rows
 from repro.models.rnn import GRUVertex, LSTMVertex
 from repro.models.treelstm import TreeLSTMVertex
 
@@ -77,6 +78,8 @@ def _run_union(fn, params, cohort, starts, width, spec):
         total += g.num_nodes
     R = total
     buf = jnp.zeros((R + 1, fn.state_dim), jnp.float32)
+    if spec is not None:
+        buf = as_rows(buf)          # the fused leg's row layout
     sent = np.int64(R)
     # Jitted like the engine's window (and solo execute's scan body):
     # the tick math must be the compiled leg, not eager dispatch.
@@ -131,7 +134,7 @@ def _run_union(fn, params, cohort, starts, width, spec):
                            jnp.asarray(node_mask), jnp.asarray(out_ids))
         tick += 1
         assert tick < 10_000
-    return per_graph, np.asarray(buf)
+    return per_graph, np.asarray(from_rows(buf) if spec is not None else buf)
 
 
 CELLS = [LSTMVertex(input_dim=5, hidden=4),
@@ -201,7 +204,8 @@ def test_frontier_megastep_pallas_matches_ref():
             jnp.asarray(child_mask_np), rows, jnp.asarray(node_mask_np),
             jnp.asarray(out), weights)
     want = np.asarray(ref.frontier_megastep(*args))
-    got = np.asarray(kops.frontier_megastep(*args, impl="pallas"))
+    got = np.asarray(from_rows(kops.frontier_megastep(
+        args[0], as_rows(buf), *args[2:], impl="pallas")))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     # Neither leg may disturb the zero sentinel.
     np.testing.assert_array_equal(got[R], np.zeros(S, np.float32))

@@ -106,12 +106,22 @@ def test_roofline_terms_and_bottleneck():
         arch="x", shape="train_4k", mesh="m", chips=256,
         hlo_flops=1e14, hlo_bytes=1e12, collective_bytes=1e11,
         collective_detail={}, model_flops_total=1e16,
-        peak_memory_bytes=1e9)
+        peak_memory_bytes=1e9, device_kind="TPU v5 lite")
     assert rep.t_compute == pytest.approx(1e14 / V5E.peak_flops)
     assert rep.t_memory == pytest.approx(1e12 / V5E.hbm_bw)
     assert rep.t_collective == pytest.approx(1e11 / V5E.ici_bw)
     assert rep.bottleneck == "collective"
     assert 0 < rep.mfu_bound <= 1.0 or rep.mfu_bound > 0
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the table by device kind; an unknown kind is an
+    error, never a silent v5e default."""
+    from repro.analysis.roofline import PEAKS, peaks_for
+    assert peaks_for("TPU v5 lite") is PEAKS["TPU v5 lite"]
+    assert peaks_for("TPU v5 lite").peak_flops == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
 
 
 def test_model_flops_moe_active():

@@ -8,7 +8,7 @@ import pytest
 
 from repro.kernels import (cell_kernels, decode_attention as dec,
                            flash_attention as fa, gather_scatter as gsc,
-                           mamba_ssd, ref)
+                           level_megastep as lm, mamba_ssd, ref)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -61,7 +61,7 @@ def test_gather_rows(r, d, n, dtype):
     k1, k2 = keys(2)
     src = jax.random.normal(k1, (r, d), dtype)
     idx = jax.random.randint(k2, (n,), 0, r, jnp.int32)
-    out = gsc.gather_rows(src, idx, interpret=True)
+    out = lm.from_rows(gsc.gather_rows(lm.as_rows(src), idx, interpret=True))
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(ref.gather_rows(src, idx)))
 
@@ -73,7 +73,8 @@ def test_scatter_rows(r, d, n):
     rows = jax.random.normal(k2, (n, d))
     idx = jnp.asarray(np.random.default_rng(0).choice(r, n, replace=False),
                       jnp.int32)
-    out = gsc.scatter_rows(dst, idx, rows, interpret=True)
+    out = lm.from_rows(gsc.scatter_rows(lm.as_rows(dst), idx,
+                                        lm.as_rows(rows), interpret=True))
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.scatter_rows(dst, idx, rows)),
                                rtol=1e-6)

@@ -138,6 +138,14 @@ def test_fused_jit_roundtrip():
 # Pallas kernels (interpret mode) vs ref oracle
 # ---------------------------------------------------------------------------
 
+def _kernel(kind, buf, cids, eids, nm, off, ext, weights):
+    """The Pallas megastep (interpret mode) on ``[R, S]`` operands: the
+    kernel itself works in the row layout ``[R, 1, S]``."""
+    out = lm.megastep(kind, lm.as_rows(buf), cids, eids, nm, jnp.int32(off),
+                      lm.as_rows(ext), weights, interpret=True)
+    return lm.from_rows(out)
+
+
 def _level_fixture(seed, M=6, H=8, T=4, A=1, n_ext=10):
     rng = np.random.default_rng(seed)
     S = 2 * H
@@ -160,8 +168,7 @@ def test_lstm_megastep_kernel_matches_ref(seed, m, h):
     buf, cids, cmask, eids, nm, off, ext, rng = _level_fixture(seed, M=m, H=h)
     wh = jnp.asarray(rng.standard_normal((h, 4 * h)) * 0.2, jnp.float32)
     b = jnp.asarray(rng.standard_normal((4 * h,)) * 0.1, jnp.float32)
-    out_p = lm.lstm_megastep(buf, cids, eids, nm, jnp.int32(off), ext, wh, b,
-                             interpret=True)
+    out_p = _kernel("lstm", buf, cids, eids, nm, off, ext, (wh, b))
     out_r = ref.level_megastep("lstm", buf, cids, cmask, eids, nm, off, ext,
                                (wh, b))
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
@@ -180,8 +187,8 @@ def test_treelstm_megastep_kernel_matches_ref(seed, m, h, a):
     ws = [jnp.asarray(rng.standard_normal((h, h)) * 0.2, jnp.float32)
           for _ in range(4)]
     b = jnp.asarray(rng.standard_normal((4 * h,)) * 0.1, jnp.float32)
-    out_p = lm.treelstm_megastep(buf, cids, eids, nm, jnp.int32(off), ext,
-                                 *ws, b, interpret=True)
+    out_p = _kernel("treelstm", buf, cids, eids, nm, off, ext,
+                    tuple(ws) + (b,))
     out_r = ref.level_megastep("treelstm", buf, cids, cmask, eids, nm, off,
                                ext, tuple(ws) + (b,))
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
@@ -206,9 +213,8 @@ def test_gru_megastep_kernel_matches_ref(seed, m, h):
     nm[-1] = 0.0
     wh = jnp.asarray(rng.standard_normal((h, 3 * h)) * 0.2, jnp.float32)
     b = jnp.asarray(rng.standard_normal((3 * h,)) * 0.1, jnp.float32)
-    out_p = lm.gru_megastep(jnp.asarray(buf), jnp.asarray(cids),
-                            jnp.asarray(eids), jnp.asarray(nm),
-                            jnp.int32(t * m), ext, wh, b, interpret=True)
+    out_p = _kernel("gru", jnp.asarray(buf), jnp.asarray(cids),
+                    jnp.asarray(eids), jnp.asarray(nm), t * m, ext, (wh, b))
     out_r = ref.level_megastep("gru", jnp.asarray(buf), jnp.asarray(cids),
                                jnp.asarray(cmask), jnp.asarray(eids),
                                jnp.asarray(nm), t * m, ext, (wh, b))
@@ -235,9 +241,8 @@ def test_treefc_megastep_kernel_matches_ref(seed, m, h, a):
     nm[-1] = 0.0
     wc = jnp.asarray(rng.standard_normal((a * h, h)) * 0.2, jnp.float32)
     b = jnp.asarray(rng.standard_normal((h,)) * 0.1, jnp.float32)
-    out_p = lm.treefc_megastep(jnp.asarray(buf), jnp.asarray(cids),
-                               jnp.asarray(eids), jnp.asarray(nm),
-                               jnp.int32(t * m), ext, wc, b, interpret=True)
+    out_p = _kernel("treefc", jnp.asarray(buf), jnp.asarray(cids),
+                    jnp.asarray(eids), jnp.asarray(nm), t * m, ext, (wc, b))
     out_r = ref.level_megastep("treefc", jnp.asarray(buf), jnp.asarray(cids),
                                jnp.asarray(cmask), jnp.asarray(eids),
                                jnp.asarray(nm), t * m, ext, (wc, b))
