@@ -313,6 +313,50 @@ def test_bwd_megastep_kernel_matches_ref(kind):
     np.testing.assert_array_equal(np.asarray(out_p)[untouched], g[untouched])
 
 
+@pytest.mark.parametrize("kind", ["lstm", "gru", "treelstm", "treefc"])
+def test_megastep_kernels_span_several_blocks(kind):
+    """A level wider than one grid block (M=136 → two blocks of 68
+    slots): forward and reverse kernels still match the oracles, with
+    children, duplicates and the sentinel spread across blocks."""
+    rng = np.random.default_rng(21)
+    h, T, M, t = 4, 3, 136, 1
+    smult = {"lstm": 2, "treelstm": 2, "gru": 1, "treefc": 1}[kind]
+    gmult = {"lstm": 4, "treelstm": 4, "gru": 3, "treefc": 1}[kind]
+    a = 1 if kind in ("lstm", "gru") else 2
+    S, G = smult * h, gmult * h
+    assert lm.block_rows(M) == 68
+    buf = rng.standard_normal((T * M + 1, S)).astype(np.float32)
+    buf[-1] = 0.0
+    g = rng.standard_normal((T * M + 1, S)).astype(np.float32)
+    cids = rng.integers(0, t * M, size=(M, a)).astype(np.int32)
+    cids[100] = cids[3]                     # duplicates across blocks
+    cids[7, -1] = cids[120, -1] = T * M     # sentinel children
+    cmask = (cids != T * M).astype(np.float32)
+    eids = rng.integers(0, 20, size=(M,)).astype(np.int32)
+    ext = rng.standard_normal((21, G)).astype(np.float32)
+    nm = (rng.random(M) > 0.1).astype(np.float32)
+    _, _, _, _, ws = _level_case(kind, 21, m=M, h=h, a=a)
+    rows = lambda x: lm.as_rows(jnp.asarray(x))   # noqa: E731
+    fwd = lm.from_rows(lm.megastep(kind, rows(buf), jnp.asarray(cids),
+                                   jnp.asarray(eids), jnp.asarray(nm),
+                                   jnp.int32(t * M), rows(ext), ws,
+                                   interpret=True))
+    fwd_r = ref.level_megastep(kind, jnp.asarray(buf), jnp.asarray(cids),
+                               jnp.asarray(cmask), jnp.asarray(eids),
+                               jnp.asarray(nm), t * M, jnp.asarray(ext), ws)
+    np.testing.assert_allclose(np.asarray(fwd), np.asarray(fwd_r),
+                               rtol=2e-5, atol=2e-5)
+    bwd = lm.from_rows(lmb.bwd_megastep(
+        kind, rows(g), rows(buf), jnp.asarray(cids), jnp.asarray(eids),
+        jnp.asarray(nm), jnp.int32(t * M), rows(ext), ws, interpret=True))
+    bwd_r = ref.bwd_megastep(kind, jnp.asarray(g), jnp.asarray(buf),
+                             jnp.asarray(cids), jnp.asarray(cmask),
+                             jnp.asarray(eids), jnp.asarray(nm), t * M,
+                             jnp.asarray(ext), ws)
+    np.testing.assert_allclose(np.asarray(bwd), np.asarray(bwd_r),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # Sorted-run scatter-add over deep buffers (the kernel holds one row in
 # VMEM at a time, whatever R and n)
