@@ -25,26 +25,30 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import level_megastep as lm
 
 
-def _gather_kernel(idx_ref, src_ref, out_ref, sem, *, bn: int):
+def _gather_kernel(idx_ref, src_ref, out_ref, rows, sem, *, bn: int):
     i0 = pl.program_id(0) * bn
-    lm.start_row_copies(src_ref, out_ref, sem.at[0], bn,
+    lm.start_row_copies(src_ref, rows, sem.at[0], bn,
                         lambda r: idx_ref[i0 + r])
-    lm.wait_row_copies(src_ref, out_ref, sem.at[0], bn)
+    lm.wait_row_copies(src_ref, rows, sem.at[0], bn)
+    lm.copy_now(rows, out_ref.at[pl.ds(i0, bn)], sem.at[1])
 
 
 def gather_rows(src: jax.Array, idx: jax.Array, *,
                 interpret: bool = False) -> jax.Array:
     """``src``: ``[R, 1, D]``; ``idx``: ``[n]`` int32 in ``[0, R)`` →
-    ``[n, 1, D]``, ``block_rows(n)`` rows per grid step."""
+    ``[n, 1, D]``, ``block_rows(n)`` rows per grid step (one DMA per
+    row into VMEM, one DMA writing the block)."""
     n = idx.shape[0]
     D = src.shape[-1]
     bn = lm.block_rows(n)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n // bn,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((bn, 1, D), lambda i, idx_ref: (i, 0, 0)),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+        in_specs=[hbm],
+        out_specs=hbm,
+        scratch_shapes=[pltpu.VMEM((bn, 1, D), src.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
     )
     return pl.pallas_call(
         functools.partial(_gather_kernel, bn=bn),
@@ -54,10 +58,11 @@ def gather_rows(src: jax.Array, idx: jax.Array, *,
     )(idx.astype(jnp.int32), src)
 
 
-def _scatter_kernel(idx_ref, dst_ref, rows_ref, out_ref, sem, *,
+def _scatter_kernel(idx_ref, dst_ref, rows_ref, out_ref, rows, sem, *,
                     bn: int, R: int):
     del dst_ref  # rides along only for the alias
     i0 = pl.program_id(0) * bn
+    lm.copy_now(rows_ref.at[pl.ds(i0, bn)], rows, sem.at[0])
 
     @pl.loop(0, bn)
     def _row(r):
@@ -65,7 +70,7 @@ def _scatter_kernel(idx_ref, dst_ref, rows_ref, out_ref, sem, *,
 
         @pl.when(jnp.logical_and(d >= 0, d < R))
         def _write():
-            lm.copy_now(rows_ref.at[r], out_ref.at[d], sem.at[0])
+            lm.copy_now(rows.at[r], out_ref.at[d], sem.at[1])
 
 
 def scatter_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array, *,
@@ -76,13 +81,14 @@ def scatter_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array, *,
     R, _, D = dst.shape
     n = idx.shape[0]
     bn = lm.block_rows(n)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n // bn,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY),              # dst
-                  pl.BlockSpec((bn, 1, D), lambda i, idx_ref: (i, 0, 0))],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+        in_specs=[hbm, hbm],
+        out_specs=hbm,
+        scratch_shapes=[pltpu.VMEM((bn, 1, D), dst.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
     )
     return pl.pallas_call(
         functools.partial(_scatter_kernel, bn=bn, R=R),
