@@ -94,8 +94,8 @@ def emit_pipeline_stages(*, n_graphs: int = 12, batch_size: int = 4,
     and calls this once per suite, so every ``BENCH_*.json`` carries
     the same stage-breakdown rows regardless of which paths the suite
     itself exercises.  The ``fwd``/``bwd`` spans time execution (the
-    programs are compiled outside the spans, and the spans block on the
-    result via ``maybe_block``)."""
+    programs are compiled outside the spans, and each span waits for
+    its result)."""
     from repro.obs import trace
     if trace.get_tracer() is None:
         return
@@ -129,9 +129,9 @@ def emit_pipeline_stages(*, n_graphs: int = 12, batch_size: int = 4,
         jax.block_until_ready(bwd(params, ext))
         with trace.correlate(batch=i):
             with trace.span("fwd", batch=i):
-                trace.maybe_block(fwd(params, ext))
+                jax.block_until_ready(fwd(params, ext))
             with trace.span("bwd", batch=i):
-                trace.maybe_block(bwd(params, ext))
+                jax.block_until_ready(bwd(params, ext))
 
 
 def add_stage_rows(col: Collector, registry=None) -> int:

@@ -60,15 +60,17 @@ def apply_grads(p, g):
 
 def train_step(p, e, dev, step):
     # Under REPRO_TRACE each step is a train.step span with nested
-    # fwd/bwd and reduce children; maybe_block brackets the device work
-    # so the spans time execution, not dispatch.  With no tracer the
-    # span sites are a single is-None check each.
+    # fwd/bwd and reduce children, which time the dispatch, and a
+    # train.sync child, which times the step's wait for the device.
+    # Spans never wait themselves.  With no tracer the span sites are a
+    # single is-None check each.
     with trace.correlate(step=step), trace.span("train.step", step=step):
         with trace.span("train.fwd_bwd"):
             l, g = fwd_bwd(p, e, dev)
-            trace.maybe_block(g)
         with trace.span("train.reduce"):
-            p = trace.maybe_block(apply_grads(p, g))
+            p = apply_grads(p, g)
+        with trace.span("train.sync"):
+            jax.block_until_ready(p)
     return l, p
 
 loss, params = train_step(params, batch.ext, batch.dev, step=0)

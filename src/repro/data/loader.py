@@ -58,6 +58,12 @@ class BackgroundPrefetcher:
             if terminal:
                 return
 
+    @property
+    def ready(self) -> int:
+        """Items queued for the consumer now (approximate, as
+        ``queue.Queue.qsize``)."""
+        return self._q.qsize()
+
     def __iter__(self) -> "BackgroundPrefetcher":
         return self
 

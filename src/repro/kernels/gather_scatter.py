@@ -55,6 +55,7 @@ def gather_rows(src: jax.Array, idx: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, D), src.dtype),
         interpret=interpret,
+        name="gather_rows",
     )(idx.astype(jnp.int32), src)
 
 
@@ -74,7 +75,8 @@ def _scatter_kernel(idx_ref, dst_ref, rows_ref, out_ref, rows, sem, *,
 
 
 def scatter_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array, *,
-                 interpret: bool = False) -> jax.Array:
+                 interpret: bool = False,
+                 name: str = "scatter_rows") -> jax.Array:
     """``dst``: ``[R, 1, D]``; ``idx``: ``[n]`` unique int32; ``rows``:
     ``[n, 1, D]`` → updated ``[R, 1, D]`` (functional; dst buffer
     aliased).  Indices outside ``[0, R)`` are dropped."""
@@ -96,4 +98,5 @@ def scatter_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
         input_output_aliases={1: 0},   # dst (first tensor operand) → out
         interpret=interpret,
+        name=name,
     )(idx.astype(jnp.int32), dst, rows.astype(dst.dtype))

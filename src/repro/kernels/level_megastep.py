@@ -253,7 +253,8 @@ def _megastep_kernel(cids_ref, eids_ref, off_ref, buf_ref, ext_ref, nm_ref,
 
 def megastep(kind: str, buf: Array, child_ids: Array, ext_ids: Array,
              node_mask: Array, offset: Array, ext: Array,
-             weights: Tuple[Array, ...], *, interpret: bool = False) -> Array:
+             weights: Tuple[Array, ...], *, interpret: bool = False,
+             name: str = "megastep_fwd") -> Array:
     """One fused batching task of gate kind ``kind``, in place.
 
     ``buf``: ``[T*M+1, 1, S]`` node-state buffer in the row layout
@@ -263,7 +264,8 @@ def megastep(kind: str, buf: Array, child_ids: Array, ext_ids: Array,
     rows of ``ext`` (``[E, 1, G]``, row layout); ``offset``: scalar
     ``t*M``; ``weights``: the cell's ``GateSpec`` weights.  The ids
     are prefetched flat into SMEM (a 2-D SMEM array pads its last dim
-    to 128 words).
+    to 128 words).  ``name`` is the kernel's stable name (the frontier
+    leg passes its own).
     """
     if kind not in _CELLS:
         raise ValueError(f"unknown megastep gate kind: {kind!r}")
@@ -294,6 +296,7 @@ def megastep(kind: str, buf: Array, child_ids: Array, ext_ids: Array,
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         input_output_aliases={3: 0},     # buf (first tensor operand) → out
         interpret=interpret,
+        name=name,
     )(child_ids.reshape(-1).astype(jnp.int32), ext_ids.astype(jnp.int32),
       jnp.reshape(offset, (1,)).astype(jnp.int32), buf, ext,
       (node_mask > 0).astype(buf.dtype).reshape(M, 1, 1), *ws)

@@ -157,6 +157,7 @@ def scatter_add_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((R, 1, D), jnp.float32),
         input_output_aliases={3: 0},   # dst (first tensor operand) → out
         interpret=interpret,
+        name="scatter_add_rows",
     )(sids, perm, head, lm.as_rows(dst.astype(jnp.float32)),
       lm.as_rows(rows.astype(jnp.float32)))
     return lm.from_rows(out).astype(dst.dtype)
@@ -277,6 +278,7 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
                    jax.ShapeDtypeStruct((A, M, 1, S), jnp.float32)),
         input_output_aliases={7: 0},   # g (second tensor operand) → out
         interpret=interpret,
+        name="megastep_bwd",
     )(child_ids.reshape(-1).astype(jnp.int32), ext_ids.astype(jnp.int32),
       jnp.reshape(offset, (1,)).astype(jnp.int32),
       sorted_child_ids.astype(jnp.int32), sort_perm.astype(jnp.int32),

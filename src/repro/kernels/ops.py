@@ -147,9 +147,11 @@ def frontier_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
         staged = lm.megastep(kind, staged, child_ids,
                              jnp.arange(M, dtype=jnp.int32), node_mask,
                              jnp.int32(ncap), lm.as_rows(rows), weights,
-                             interpret=_interpret())
+                             interpret=_interpret(),
+                             name="frontier_megastep")
         return gsc.scatter_rows(buf, out_ids, staged[ncap:],
-                                interpret=_interpret())
+                                interpret=_interpret(),
+                                name="frontier_scatter")
     return lm.as_rows(ref.frontier_megastep(
         kind, lm.from_rows(buf), child_ids, child_mask, rows, node_mask,
         out_ids, weights))

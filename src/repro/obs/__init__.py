@@ -16,12 +16,12 @@ from repro.obs.registry import (MetricsRegistry, fresh_registry,
                                 get_registry, set_registry)
 from repro.obs.trace import (Span, SpanHandle, Tracer, begin, correlate,
                              enabled, end, get_tracer, install_tracer,
-                             instant, maybe_block, maybe_install_from_env,
-                             set_tracer, span, validate_spans)
+                             instant, maybe_install_from_env, set_tracer,
+                             span, validate_spans)
 
 __all__ = [
     "MetricsRegistry", "fresh_registry", "get_registry", "set_registry",
     "Span", "SpanHandle", "Tracer", "begin", "correlate", "enabled",
-    "end", "get_tracer", "install_tracer", "instant", "maybe_block",
+    "end", "get_tracer", "install_tracer", "instant",
     "maybe_install_from_env", "set_tracer", "span", "validate_spans",
 ]

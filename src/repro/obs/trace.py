@@ -32,6 +32,23 @@ stamps every span/instant begun inside the block (on that thread) with
 ``step=n``.  Conventions: ``step`` = trainer optimizer step, ``batch``
 = pipeline pack sequence number, ``request`` = serving request id.
 
+Spans time host work and never wait on the device themselves.  Where
+the program waits (the trainer's non-finite guard, a serving window's
+``block_until_ready``, a host read-back), that wait has a span of its
+own (``train.sync``, ``cb.wait``, ``cb.project``, ``cb.readback``); a
+span around an asynchronous dispatch times the dispatch.  Installing a
+tracer therefore adds no device sync: what runs traced runs as it runs
+untraced.
+
+One clock with the device trace: while a tracer is installed, every
+span also opens a ``jax.profiler.TraceAnnotation`` of the same name on
+its thread, so a ``jax.profiler`` capture shows the program's spans,
+nested as here, beside the device's operations.  An annotation must
+end on the thread that began it: a ``begin`` handle ended on another
+thread keeps its span here but gets no annotation (its annotation is
+dropped unrecorded).  Outside a capture an annotation records nothing.
+``Span.ts`` stays on ``time.perf_counter_ns``.
+
 Activation: ``REPRO_TRACE=<path>`` (or ``=1`` for ``trace.json``) in
 the environment installs a tracer at ``import repro`` time and
 registers an atexit flush to Chrome trace-event JSON — open the file in
@@ -50,7 +67,7 @@ from typing import Any, Dict, Iterable, List, Optional
 
 __all__ = [
     "Span", "SpanHandle", "Tracer",
-    "span", "instant", "correlate", "begin", "end", "maybe_block",
+    "span", "instant", "correlate", "begin", "end",
     "enabled", "get_tracer", "set_tracer", "install_tracer",
     "maybe_install_from_env", "validate_spans",
 ]
@@ -90,15 +107,18 @@ class Span:
 
 
 class SpanHandle:
-    """An open span (explicit begin/end API).  ``end()`` is idempotent
-    — a double end is counted, not raised — and may run on a different
-    thread than ``begin`` (the span stays on its begin thread's lane)."""
+    """An open span (explicit begin/end API; also the context manager
+    ``span()`` returns).  ``end()`` is idempotent — a double end is
+    counted, not raised — and may run on a different thread than
+    ``begin`` (the span stays on its begin thread's lane, and its
+    profiler annotation is dropped: see the module docstring)."""
 
-    __slots__ = ("_tracer", "name", "t0", "tid", "cid", "attrs", "_open")
+    __slots__ = ("_tracer", "name", "t0", "tid", "cid", "attrs", "_open",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, t0: int, tid: int,
                  cid: Optional[Dict[str, Any]],
-                 attrs: Optional[Dict[str, Any]]):
+                 attrs: Optional[Dict[str, Any]], ann: Any = None):
         self._tracer = tracer
         self.name = name
         self.t0 = t0
@@ -106,12 +126,26 @@ class SpanHandle:
         self.cid = cid
         self.attrs = attrs
         self._open = True
+        self._ann = ann
+
+    def __enter__(self) -> "SpanHandle":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        return False
 
     def end(self, **extra: Any) -> None:
         if not self._open:
             self._tracer.double_ends += 1
             return
         self._open = False
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            if threading.get_ident() == self.tid:
+                ann.__exit__(None, None, None)
+            else:
+                self._tracer._strand(ann)
         t1 = time.perf_counter_ns()
         attrs = self.attrs
         if extra:
@@ -144,6 +178,12 @@ class Tracer:
         self.thread_names: Dict[int, str] = {}
         self._lock = threading.Lock()
         self._tls = _Tls()
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        #: Annotations of handles ended off their begin thread: never
+        #: ended (that would record them on the wrong thread), released
+        #: once no profiler capture is running, when they record nothing.
+        self._stranded: List[Any] = []
 
     # -- core -------------------------------------------------------------
     def begin(self, name: str, **attrs: Any) -> SpanHandle:
@@ -151,11 +191,19 @@ class Tracer:
         if tid not in self.thread_names:
             self.thread_names[tid] = threading.current_thread().name
         cid = self._tls.cid
-        h = SpanHandle(self, name, time.perf_counter_ns(), tid,
-                       dict(cid) if cid else None, attrs or None)
+        t0 = time.perf_counter_ns()
+        if self._stranded and not self._annotation.is_enabled():
+            with self._lock:
+                self._stranded.clear()
+        h = SpanHandle(self, name, t0, tid, dict(cid) if cid else None,
+                       attrs or None, self._annotation(name))
         with self._lock:
             self.open_spans += 1
         return h
+
+    def _strand(self, ann: Any) -> None:
+        with self._lock:
+            self._stranded.append(ann)
 
     def end(self, handle: SpanHandle, **extra: Any) -> None:
         handle.end(**extra)
@@ -168,13 +216,9 @@ class Tracer:
         if self.registry is not None:
             self.registry.observe(f"span.{sp.name}", sp.dur_ms)
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any):
-        h = self.begin(name, **attrs)
-        try:
-            yield h
-        finally:
-            h.end()
+    def span(self, name: str, **attrs: Any) -> SpanHandle:
+        """A span as a context manager: begun here, ended on exit."""
+        return self.begin(name, **attrs)
 
     def instant(self, name: str, **attrs: Any) -> None:
         tid = threading.get_ident()
@@ -327,16 +371,6 @@ def begin(name: str, **attrs: Any) -> Optional[SpanHandle]:
 def end(handle: Optional[SpanHandle], **extra: Any) -> None:
     if handle is not None:
         handle.end(**extra)
-
-
-def maybe_block(x):
-    """``jax.block_until_ready(x)`` ONLY when tracing is on — brackets
-    device work so a span measures execution, not dispatch, without
-    serializing untraced runs.  Returns ``x``."""
-    if _TRACER is not None:
-        import jax
-        jax.block_until_ready(x)
-    return x
 
 
 # ---------------------------------------------------------------------------

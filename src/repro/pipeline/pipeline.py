@@ -143,8 +143,8 @@ class SchedulePipeline:
             # hook): poisons whole per-sample blocks, so a NaN can only
             # reach the sample it was injected into.
             ext_np = chaos_corrupt_ext(ext_np, sched)
-            with trace.span("h2d.ext"):
-                ext = trace.maybe_block(jnp.asarray(ext_np))
+            with trace.span("h2d.ext"):          # the copy's dispatch
+                ext = jnp.asarray(ext_np)
         return PackedBatch(sched=sched, dev=dev, ext=ext,
                            aux=dict(aux or {}))
 

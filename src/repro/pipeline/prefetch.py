@@ -18,6 +18,14 @@ contract) are retried in place up to ``retries`` times before
 surfacing, WITHOUT dropping the item being packed: a blip on the
 background thread must not silently lose a batch from the stream.
 Deterministic errors (bad data, shape mismatches) are never retried.
+
+Spans: on the producer thread ``prefetch.source`` (drawing the next
+item from the source, where a composed epoch is built) and
+``prefetch.pack`` (packing it), both with ``seq``, the batch's index in
+the stream; on the consumer thread ``prefetch.wait`` (taking the next
+ready batch) with the ``seq`` it consumes, which is the ``seq`` of the
+``prefetch.pack`` that produced it (FIFO), and ``ready``, the batches
+queued on entry (0: the consumer waited on the producer).
 """
 
 from __future__ import annotations
@@ -42,11 +50,13 @@ class AsyncPacker:
         self._pack_fn = pack_fn
         self._retries = retries
         self.packed = 0                   # batches produced so far
+        self.consumed = 0                 # batches handed to the consumer
         self.transient_retries = 0        # SimulatedFailures absorbed
         self._bg = BackgroundPrefetcher(self._produce, depth=depth)
 
     def _produce(self) -> Any:
-        item = next(self._source)         # StopIteration ends the stream
+        with trace.span("prefetch.source", seq=self.packed):
+            item = next(self._source)     # StopIteration ends the stream
         attempt = 0
         # Explicit begin/end (not the context manager): the producer
         # runs on the prefetch thread, and a retried pack is still ONE
@@ -76,7 +86,12 @@ class AsyncPacker:
         return self
 
     def __next__(self) -> Any:
-        return next(self._bg)
+        seq = self.consumed
+        ready = self._bg.ready if trace.enabled() else 0
+        with trace.span("prefetch.wait", seq=seq, ready=ready):
+            item = next(self._bg)
+        self.consumed = seq + 1
+        return item
 
     def close(self) -> None:
         self._bg.close()

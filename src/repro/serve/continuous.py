@@ -267,6 +267,7 @@ class ContinuousBatchEngine(_EngineBase):
         # pad lanes scatter out of range and are dropped).
         S = fn.state_dim
         self._buf = jnp.zeros((num_rows + 1, S), jnp.float32)
+        self._arena_bytes = int(self._buf.nbytes)   # one whole readback
         self._free: List[int] = list(range(num_rows - 1, -1, -1))
         self._active: List[_Active] = []
         self._project = (jax.jit(fn.project_inputs)
@@ -353,10 +354,12 @@ class ContinuousBatchEngine(_EngineBase):
         if ticks:
             with trace.span("cb.stack", ticks=len(ticks)):
                 args = self._stack_window(ticks)
+            lanes = (sum(len(p[0]) for parts in ticks for p in parts)
+                     if trace.enabled() else 0)
             try:
                 with trace.span("cb.window", ticks=len(ticks),
-                                fused=self.fused):
-                    self._buf = trace.maybe_block(self._run_window(args))
+                                fused=self.fused, lanes=lanes):
+                    self._buf = self._run_window(args)
             except Exception as e:       # noqa: BLE001 — oracle failed too
                 # Both rungs of the ladder failed: the window is lost
                 # (the buffer was not advanced), so every in-flight
@@ -448,7 +451,10 @@ class ContinuousBatchEngine(_EngineBase):
         raw[: x.shape[0]] = x
         raw = chaos_corrupt_ext(raw, _ExtShim(plan.n_pad))
         if self._project is not None:
-            return np.asarray(self._project(self.params, jnp.asarray(raw)))
+            # A device round trip: the projection runs and comes back.
+            with trace.span("cb.project", rows=plan.n_pad + 1):
+                return np.asarray(self._project(self.params,
+                                                jnp.asarray(raw)))
         return raw
 
     # -- window planning ------------------------------------------------------
@@ -556,7 +562,8 @@ class ContinuousBatchEngine(_EngineBase):
             try:
                 chaos_fire("kernel")
                 out = self._window(*args)
-                out.block_until_ready()  # surface async kernel failures
+                with trace.span("cb.wait"):
+                    out.block_until_ready()  # surface async kernel failures
                 self._breaker.record_success()
                 return out
             except Exception as e:       # noqa: BLE001 — degrade
@@ -601,7 +608,8 @@ class ContinuousBatchEngine(_EngineBase):
         heads — the lazy ``push`` made immediate.  One whole-buffer
         host readback, indexed in numpy: a per-count device gather
         would recompile for every retirement batch size."""
-        with trace.span("cb.readback", count=len(done)):
+        with trace.span("cb.readback", count=len(done),
+                        bytes=self._arena_bytes):
             buf_np = np.asarray(self._buf)
         roots = buf_np[[a.root_row for a in done]]
         ok: List[ContinuousRequest] = []

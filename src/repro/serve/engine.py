@@ -51,6 +51,12 @@ kernel failure (a :class:`~repro.serve.robustness.CircuitBreaker` pins
 the oracle after ``breaker_threshold`` consecutive failures), and
 :class:`StructureServeEngine` quarantines poisoned batches by
 bisection so one bad request never takes down its co-batched peers.
+
+Spans (``repro.obs.trace``) time host work: ``serve.decode``,
+``serve.tick`` and ``serve.score`` time the dispatch of their programs,
+and ``serve.wait`` names each point where an engine itself waits on the
+device (the decode's sampled tokens read back, a fused tick's or
+batch's ``block_until_ready``).  No span adds a wait of its own.
 """
 
 from __future__ import annotations
@@ -207,13 +213,13 @@ class ServeEngine(_EngineBase):
         with trace.span("serve.decode", active=int(self.slots.num_active)):
             logits, new_cache = self._decode(self.params, self.slots.cache,
                                              tokens, positions)
-            trace.maybe_block(logits)
         self.slots.cache = new_cache
         next_tok = self._sample(logits)
         self.slots.advance()
         self.ticks += 1
 
-        next_np = np.asarray(next_tok)
+        with trace.span("serve.wait"):
+            next_np = np.asarray(next_tok)
         for slot in range(self.num_slots):
             if not self.slots.active[slot]:
                 continue
@@ -454,7 +460,7 @@ class VertexServeEngine(_EngineBase):
         try:
             with trace.span("serve.tick", active=self.num_active,
                             fused=self.fused):
-                self._buf = trace.maybe_block(self._run_tick(args))
+                self._buf = self._run_tick(args)
         except Exception as e:           # noqa: BLE001 — oracle failed too
             # Both rungs of the ladder failed: the whole tick is lost
             # (the buffer was not advanced), so every in-flight request
@@ -506,7 +512,8 @@ class VertexServeEngine(_EngineBase):
             try:
                 chaos_fire("kernel")
                 out = self._tick(*args)
-                out.block_until_ready()  # surface async kernel failures
+                with trace.span("serve.wait"):
+                    out.block_until_ready()  # surface async kernel failures
                 self._breaker.record_success()
                 return out
             except Exception as e:       # noqa: BLE001 — degrade
@@ -707,7 +714,8 @@ class StructureServeEngine(_EngineBase):
             try:
                 chaos_fire("kernel")
                 out = self._run(self.params, dev, ext)
-                out.block_until_ready()  # surface async kernel failures
+                with trace.span("serve.wait"):
+                    out.block_until_ready()  # surface async kernel failures
                 self._breaker.record_success()
                 return out
             except Exception as e:       # noqa: BLE001 — degrade

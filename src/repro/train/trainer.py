@@ -512,7 +512,8 @@ class Trainer:
         correlation).  Returns ``(state, done, skips_in_row)``; a fault
         recovery leaves ``done`` rewound instead of advanced."""
         cfg = self.cfg
-        batch = next(batches)
+        with trace.span("train.next_batch"):
+            batch = next(batches)
         with trace.span("train.h2d"):
             batch = {k: jax.tree.map(jnp.asarray, v)
                      for k, v in batch.items()}
@@ -522,7 +523,6 @@ class Trainer:
                 fault_injector.tick(done)
             with trace.span("train.fwd_bwd"):
                 state, metrics = self._train_step(state, batch)
-                trace.maybe_block(metrics)
         except _FAULTS as e:
             if self.ckpt is None:
                 raise
@@ -551,11 +551,12 @@ class Trainer:
                 state, done = self.maybe_restore(zeros)
             return state, done, skips_in_row
         if cfg.skip_nonfinite:
-            # NOTE this read syncs on the step's metrics, so the
-            # train_tick below measures executed train work (not just
-            # dispatch) whenever the guard is on — and always does
-            # under tracing, via the maybe_block above.
-            if float(np.asarray(metrics.get("skipped", 0.0))) > 0:
+            # This read is the step's one device sync (``train.fwd_bwd``
+            # times the dispatch), so the train_tick below measures
+            # executed train work whenever the guard is on.
+            with trace.span("train.sync"):
+                skipped = float(np.asarray(metrics.get("skipped", 0.0)))
+            if skipped > 0:
                 skips_in_row += 1
                 logger.count("nonfinite_skips")
                 if skips_in_row > cfg.max_skip_steps:

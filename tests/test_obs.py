@@ -99,7 +99,25 @@ def test_begin_end_cross_thread_and_double_end():
     assert t.open_spans == 0
 
 
-def test_disabled_paths_are_noops():
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts opens."""
+
+    opened = 0
+
+    def __init__(self, name):
+        type(self).opened += 1
+
+    def __exit__(self, *exc):
+        return False
+
+    @staticmethod
+    def is_enabled():
+        return False
+
+
+def test_disabled_paths_are_noops(monkeypatch):
+    monkeypatch.setattr(_CountingAnnotation, "opened", 0)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
     with trace.install_tracer(None):     # force OFF (CI sets REPRO_TRACE)
         assert not trace.enabled()
         with trace.span("x", a=1) as h:
@@ -107,10 +125,15 @@ def test_disabled_paths_are_noops():
         assert trace.begin("y") is None
         trace.end(None, extra=1)          # accepts the disabled handle
         trace.instant("z")
-        obj = object()
-        assert trace.maybe_block(obj) is obj
         with trace.correlate(step=1):
             pass
+    # The profiler mirror is off with the tracer: no annotation opened.
+    assert _CountingAnnotation.opened == 0
+    with trace.install_tracer(Tracer()):
+        with trace.span("x"):
+            pass
+        trace.end(trace.begin("y"))
+    assert _CountingAnnotation.opened == 2
 
 
 def test_bounded_deque_counts_drops():
@@ -543,3 +566,231 @@ def test_kernel_dispatch_counters():
         ops.gather_rows(x, idx, impl="jax")
         assert reg.counter("kernel.dispatch", op="gather_rows",
                            impl="jax") == 1
+
+
+# ---------------------------------------------------------------------------
+# Spans never sync the device; the profiler sees them
+# ---------------------------------------------------------------------------
+
+_LSTM_IN, _LSTM_H = 4, 3
+
+
+def _composed_trainer():
+    """A small LSTM trainer fed by the composer through the pipeline's
+    background packer (``SchedulePipeline.prefetch``); returns the state
+    after two (compiling) steps and a ``fit(state, steps)``."""
+    from repro.core.scheduler import execute, readout_roots
+    from repro.models.rnn import LSTMVertex
+    from repro.train import TrainConfig, Trainer
+
+    fn = LSTMVertex(input_dim=_LSTM_IN, hidden=_LSTM_H)
+    rng = np.random.default_rng(0)
+    graphs = [chain(int(rng.integers(2, 7))) for _ in range(12)]
+    inputs = [rng.standard_normal((g.num_nodes, _LSTM_IN)
+                                  ).astype(np.float32) for g in graphs]
+    targets = list(rng.standard_normal((12, _LSTM_H)).astype(np.float32))
+
+    def loss_fn(p, b):
+        buf = execute(fn, p, b["dev"], b["ext"], fusion_mode="none").buf
+        h = readout_roots(buf, b["dev"])[:, _LSTM_H:]
+        return jnp.mean((h - b["target"]) ** 2), {}
+
+    def epochs():
+        while True:
+            yield graphs, inputs, {"target": targets}
+
+    tr = Trainer(loss_fn, fn.init,
+                 TrainConfig(lr=1e-2, warmup_steps=1, total_steps=100,
+                             weight_decay=0.0, log_every=2))
+    pipe = SchedulePipeline(ext_dim=_LSTM_IN)
+
+    def fit(state, steps):
+        state, _ = tr.fit(state, epochs(), steps=steps,
+                          compose=pipe.composer(4), pipeline=pipe,
+                          logger=MetricLogger(log_fn=lambda *_: None))
+        return state
+
+    return fit(tr.init_state(jax.random.PRNGKey(0)), 2), fit
+
+
+def _engine_and_requests(n=4, seed=0):
+    from repro.core.structure import random_binary_tree
+    from repro.models.treelstm import TreeLSTMVertex
+    from repro.serve import ContinuousBatchEngine, ContinuousRequest
+
+    fn = TreeLSTMVertex(input_dim=_LSTM_IN, hidden=_LSTM_H, arity=2)
+    params = fn.init(jax.random.PRNGKey(1))
+    rng = np.random.default_rng(seed)
+    reqs = []
+    for i in range(n):
+        g = random_binary_tree(int(rng.integers(2, 6)), rng)
+        reqs.append(ContinuousRequest(
+            i, g, rng.standard_normal((g.num_nodes, _LSTM_IN)
+                                      ).astype(np.float32)))
+    eng = ContinuousBatchEngine(fn, params, num_rows=32, frontier_width=4,
+                                fusion_mode="megastep", clock=lambda: 0.0)
+    return eng, reqs
+
+
+class _SyncCounter:
+    """Counts device syncs: ``jax.block_until_ready`` and the array
+    method that it and the engines call."""
+
+    def __init__(self, monkeypatch):
+        from jax._src.array import ArrayImpl
+        self.n = 0
+        fn, meth = jax.block_until_ready, ArrayImpl.block_until_ready
+
+        def count_fn(x):
+            self.n += 1
+            return fn(x)
+
+        def count_meth(a):
+            self.n += 1
+            return meth(a)
+
+        monkeypatch.setattr(jax, "block_until_ready", count_fn)
+        monkeypatch.setattr(ArrayImpl, "block_until_ready", count_meth)
+
+
+@pytest.mark.parametrize("path", ["trainer_fit", "engine_run"])
+def test_tracer_adds_no_device_sync(path, monkeypatch):
+    """Installing a tracer changes no device sync: a short composed
+    ``Trainer.fit`` (prefetch thread included) and a
+    ``ContinuousBatchEngine.run`` make as many ``block_until_ready``
+    calls traced as untraced."""
+    counts = {}
+    if path == "trainer_fit":
+        state, fit = _composed_trainer()       # compiled, untraced
+        counter = _SyncCounter(monkeypatch)
+        for traced, steps in ((False, 4), (True, 6)):
+            counter.n = 0
+            with trace.install_tracer(Tracer() if traced else None):
+                state = fit(state, steps)
+            counts[traced] = counter.n
+    else:
+        warm, reqs = _engine_and_requests()
+        for r in reqs:
+            warm.submit(r)
+        warm.run()                             # compiles every window
+        counter = _SyncCounter(monkeypatch)
+        for traced in (False, True):
+            eng, reqs = _engine_and_requests()
+            for r in reqs:
+                eng.submit(r)
+            t = Tracer() if traced else None
+            counter.n = 0
+            with trace.install_tracer(t):
+                eng.run()
+            assert all(r.status == "ok" for r in reqs)
+            counts[traced] = counter.n
+        assert counts[False] > 0               # the engine's own waits
+        assert any(sp.name == "cb.wait" for sp in t.snapshot())
+    assert counts[True] == counts[False], counts
+
+
+def test_traced_fit_names_batch_wait_and_sync():
+    """A traced composed ``fit``: ``train.next_batch`` holds the
+    consumer's ``prefetch.wait``; each wait's ``seq`` is the ``seq`` of
+    exactly one producer-side ``prefetch.pack``; the guard's read is
+    ``train.sync``; the producer draws items under
+    ``prefetch.source``."""
+    state, fit = _composed_trainer()
+    t = Tracer()
+    with trace.install_tracer(t):
+        fit(state, 6)
+    spans = t.snapshot()
+    assert validate_spans(spans) == [] and t.open_spans == 0
+    main = threading.get_ident()
+    by = collections.defaultdict(list)
+    for sp in spans:
+        by[sp.name].append(sp)
+    steps = by["train.step"]
+    assert len(steps) == 4
+    assert len(by["train.next_batch"]) == 4 and len(by["train.sync"]) == 4
+    waits = by["prefetch.wait"]
+    assert len(waits) == 4 and all(w.tid == main for w in waits)
+    for w in waits:
+        outer = [n for n in by["train.next_batch"]
+                 if n.ts <= w.ts and w.ts + w.dur <= n.ts + n.dur]
+        assert len(outer) == 1
+        assert set(w.attrs) == {"seq", "ready"} and w.attrs["ready"] >= 0
+    packs = by["prefetch.pack"]
+    assert packs and all(p.tid != main for p in packs)
+    assert by["prefetch.source"]
+    assert all(s.tid != main for s in by["prefetch.source"])
+    pack_seqs = collections.Counter(p.attrs["seq"] for p in packs)
+    assert [w.attrs["seq"] for w in waits] == [0, 1, 2, 3]
+    for w in waits:
+        assert pack_seqs[w.attrs["seq"]] == 1
+    for sync in by["train.sync"]:
+        assert any(s.ts <= sync.ts and sync.ts + sync.dur <= s.ts + s.dur
+                   for s in steps)
+
+
+def test_spans_appear_in_profiler_capture(tmp_path):
+    """Under a ``jax.profiler`` capture (CPU backend) a program span is
+    an event of the host plane under its own name, nested inside its
+    parent on the same thread's line; a handle ended off its thread
+    leaves no event there."""
+    t = Tracer()
+    with trace.install_tracer(t):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("obs.test_outer"):
+                with trace.span("obs.test_inner", k=1):
+                    jnp.ones(4).block_until_ready()
+            h = trace.begin("obs.test_cross")
+            th = threading.Thread(target=trace.end, args=(h,))
+            th.start()
+            th.join()
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = [str(p) for p in tmp_path.rglob("*.xplane.pb")]
+    pd = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs.test_"):
+                    found[e.name] = (line.name, e.start_ns, e.end_ns)
+    assert set(found) == {"obs.test_outer", "obs.test_inner"}, found
+    outer, inner = found["obs.test_outer"], found["obs.test_inner"]
+    assert outer[0] == inner[0]                        # one thread's line
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert {sp.name for sp in t.snapshot()} == {
+        "obs.test_outer", "obs.test_inner", "obs.test_cross"}
+
+
+def test_engine_names_its_device_waits():
+    """The continuous engine names its waits (``cb.wait`` in each fused
+    window, ``cb.project`` at each admission, ``cb.readback`` with the
+    arena's ``bytes``) and counts each window's real ``lanes``."""
+    eng, reqs = _engine_and_requests(n=5, seed=3)
+    for r in reqs:
+        eng.submit(r)
+    t = Tracer()
+    with trace.install_tracer(t):
+        eng.run()
+    assert all(r.status == "ok" for r in reqs)
+    spans = t.snapshot()
+    assert validate_spans(spans) == []
+    by = collections.defaultdict(list)
+    for sp in spans:
+        by[sp.name].append(sp)
+    windows = by["cb.window"]
+    assert windows and len(by["cb.wait"]) == len(windows)
+    for w in by["cb.wait"]:
+        assert any(win.ts <= w.ts and w.ts + w.dur <= win.ts + win.dur
+                   for win in windows)
+    # Every vertex runs in exactly one lane of one tick.
+    assert sum(w.attrs["lanes"] for w in windows) == sum(
+        r.graph.num_nodes for r in reqs)
+    assert all(0 < w.attrs["lanes"] <= w.attrs["ticks"] * 4
+               for w in windows)
+    assert len(by["cb.project"]) == len(reqs) == len(by["cb.admit"])
+    assert by["cb.readback"]
+    for rb in by["cb.readback"]:
+        assert rb.attrs["bytes"] == (32 + 1) * eng.fn.state_dim * 4
