@@ -53,7 +53,10 @@ baseline (selectable via ``REPRO_FUSION=none`` /
 ``REPRO_KERNEL_IMPL=chunked``).  The parameter/external gradients are
 computed lazily in one flat batched pass (§3.5) — so both
 :func:`execute` and :func:`execute_lazy` share one backward, with
-activations recomputed from the node buffer (remat).
+activations recomputed from the node buffer (remat).  Both scans hand
+the kernels each level's live extent (``megastep.live_ids``, built
+once outside the scan), so the grid blocks that hold only padding,
+and the backward's walk past the level's last real edge, are skipped.
 """
 
 from __future__ import annotations
@@ -189,6 +192,7 @@ def _megastep_scan(spec: GateSpec, weights, sched: DeviceSchedule,
     ``[·, 1, ·]`` the kernels DMA rows of (``megastep.as_rows``)."""
     T, M = sched.T, sched.M
     S = spec.state_dim
+    # Blocks left out of the megasteps (no real vertex) keep these zeros.
     buf0 = jnp.zeros((T * M + 1, 1, S), dtype)
 
     def step(buf, xs):
@@ -198,9 +202,16 @@ def _megastep_scan(spec: GateSpec, weights, sched: DeviceSchedule,
         return buf, None
 
     xs = (jnp.arange(T, dtype=jnp.int32), sched.child_ids, sched.child_mask,
-          sched.ext_ids, sched.node_mask)
+          _live_ids(sched), sched.node_mask)
     buf, _ = jax.lax.scan(step, buf0, xs)
     return buf
+
+
+def _live_ids(sched: DeviceSchedule) -> Array:
+    """Each level's ext ids with its live blocks and real-edge count
+    appended (``megastep.live_ids``), computed outside the level scan."""
+    return megastep.live_ids(sched.ext_ids, sched.node_mask, sched.child_ids,
+                             sched.T * sched.M)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -258,7 +269,7 @@ def _megastep_bwd(fn, res, g_buf):
         return g, None
 
     xs = (jnp.arange(T, dtype=jnp.int32), sched.child_ids, sched.child_mask,
-          sched.ext_ids, sched.node_mask)
+          _live_ids(sched), sched.node_mask)
     if have_runs:
         xs = xs + (sched.sort_perm, sched.sorted_child_ids, sched.run_head)
     g_final, _ = jax.lax.scan(

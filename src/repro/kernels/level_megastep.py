@@ -56,6 +56,15 @@ rows ``[offset + m0, offset + m0 + bm)``.  Child ``a`` of every slot
 lands in its own ``[bm, 1, S]`` scratch, so the cell math sees the
 children as ``A`` separate ``[bm, S]`` tiles.
 
+Padded blocks.  Bucketed schedules pad levels and widths, and most
+blocks of a padded level hold no vertex.  The scheduler appends each
+level's live extent to the ext-id scalar operand (:func:`live_ids`:
+one flag per block, then the count of real edges); a block whose flag
+is 0 starts no DMA, runs no math and writes nothing, so its rows keep
+the zeros the scan's buffer starts with — what the masked write gave.
+A caller that passes the plain ``[M]`` ext ids (the serving frontier,
+hand-built levels) runs every block.
+
 VMEM budget.  Weights are single-buffered (their block never changes);
 per grid step VMEM holds the ``A`` child tiles ``[bm, 1, S]``, the ext
 rows ``[bm, 1, G]``, the state block and the gate temporaries.  The
@@ -228,27 +237,77 @@ def gate_weights(weights: Tuple[Array, ...]) -> Tuple[Array, ...]:
 # ---------------------------------------------------------------------------
 
 def _megastep_kernel(cids_ref, eids_ref, off_ref, buf_ref, ext_ref, nm_ref,
-                     *rest, kind: str, A: int, bm: int, nw: int):
+                     *rest, kind: str, A: int, bm: int, nw: int, M: int,
+                     gated: bool):
     w_refs = rest[:nw]
     out_ref, chd, exv, st, sem = rest[nw:]
-    m0 = pl.program_id(0) * bm
+    i = pl.program_id(0)
+    m0 = i * bm
     S = st.shape[-1]
-    # (a) gather: one DMA per child row and per pulled ext row.
-    for a in range(A):
-        start_row_copies(buf_ref, chd.at[a], sem.at[0], bm,
-                         lambda r, a=a: cids_ref[(m0 + r) * A + a])
-    start_row_copies(ext_ref, exv, sem.at[1], bm, lambda r: eids_ref[m0 + r])
-    for a in range(A):
-        wait_row_copies(buf_ref, chd.at[a], sem.at[0], bm)
-    wait_row_copies(ext_ref, exv, sem.at[1], bm)
-    # (b) cell: gate math on [bm, ·] tiles against resident weights.
-    children = [chd[a].reshape(bm, S).astype(jnp.float32) for a in range(A)]
-    ext = exv[...].reshape(bm, exv.shape[-1]).astype(jnp.float32)
-    state = _CELLS[kind](children, ext, tuple(w[...].astype(jnp.float32)
-                                              for w in w_refs))
-    st[...] = (state.reshape(bm, 1, S) * nm_ref[...]).astype(st.dtype)
-    # (c) scatter: the block's rows are contiguous — one DMA.
-    copy_now(st, out_ref.at[pl.ds(off_ref[0] + m0, bm)], sem.at[2])
+
+    def block():
+        # (a) gather: one DMA per child row and per pulled ext row.
+        for a in range(A):
+            start_row_copies(buf_ref, chd.at[a], sem.at[0], bm,
+                             lambda r, a=a: cids_ref[(m0 + r) * A + a])
+        start_row_copies(ext_ref, exv, sem.at[1], bm,
+                         lambda r: eids_ref[m0 + r])
+        for a in range(A):
+            wait_row_copies(buf_ref, chd.at[a], sem.at[0], bm)
+        wait_row_copies(ext_ref, exv, sem.at[1], bm)
+        # (b) cell: gate math on [bm, ·] tiles against resident weights.
+        children = [chd[a].reshape(bm, S).astype(jnp.float32)
+                    for a in range(A)]
+        ext = exv[...].reshape(bm, exv.shape[-1]).astype(jnp.float32)
+        state = _CELLS[kind](children, ext, tuple(w[...].astype(jnp.float32)
+                                                  for w in w_refs))
+        st[...] = (state.reshape(bm, 1, S) * nm_ref[...]).astype(st.dtype)
+        # (c) scatter: the block's rows are contiguous — one DMA.
+        copy_now(st, out_ref.at[pl.ds(off_ref[0] + m0, bm)], sem.at[2])
+
+    if gated:
+        # A block with no real vertex starts no DMA and writes nothing:
+        # its rows keep the zeros they were allocated with.
+        pl.when(eids_ref[M + i] != 0)(block)
+    else:
+        block()
+
+
+def block_live(node_mask):
+    """``[T, M]`` node mask → ``[T, M // block_rows(M)]`` booleans: true
+    where a grid block of the megastep kernels holds a real vertex.
+    Takes a numpy or a jax array."""
+    T, M = node_mask.shape
+    bm = block_rows(M)
+    return (node_mask.reshape(T, M // bm, bm) > 0).any(axis=-1)
+
+
+def live_ids(ext_ids: Array, node_mask: Array, child_ids: Array,
+             sentinel: int) -> Array:
+    """Each level's ext ids with its live extent appended, so that the
+    megastep kernels skip what holds no vertex: ``[T, M]`` → ``[T, M +
+    nb + 1]`` int32, the ``M`` ext ids, then :func:`block_live`'s ``nb``
+    flags, then the level's count of child ids other than ``sentinel``
+    (its real edges: the sorted-run walk of the backward stops there).
+    Computed once for the whole schedule, outside the level scans."""
+    T = ext_ids.shape[0]
+    edges = jnp.sum(child_ids.reshape(T, -1) != sentinel, axis=1,
+                    keepdims=True)
+    return jnp.concatenate([ext_ids.astype(jnp.int32),
+                            block_live(node_mask).astype(jnp.int32),
+                            edges.astype(jnp.int32)], axis=1)
+
+
+def level_extent(M: int, ext_ids: Array) -> Tuple[int, bool]:
+    """``(nb, gated)`` of a level of width ``M``: its grid blocks, and
+    whether ``ext_ids`` carries :func:`live_ids`'s flags (length ``M +
+    nb + 1``) or is the plain ``[M]`` vector."""
+    nb = M // block_rows(M)
+    n = ext_ids.shape[0]
+    if n not in (M, M + nb + 1):
+        raise ValueError(f"ext_ids must hold {M} ids, or {M + nb + 1} with "
+                         f"the live flags of live_ids; got {n}")
+    return nb, n > M
 
 
 def megastep(kind: str, buf: Array, child_ids: Array, ext_ids: Array,
@@ -266,6 +325,11 @@ def megastep(kind: str, buf: Array, child_ids: Array, ext_ids: Array,
     are prefetched flat into SMEM (a 2-D SMEM array pads its last dim
     to 128 words).  ``name`` is the kernel's stable name (the frontier
     leg passes its own).
+
+    ``ext_ids`` may instead be a level's row of :func:`live_ids`: a
+    block whose flag is 0 is then skipped — no DMA, no math, no write —
+    so its rows keep what ``buf`` held, which must be zeros (the masked
+    write would give them).  Without the flags every block runs.
     """
     if kind not in _CELLS:
         raise ValueError(f"unknown megastep gate kind: {kind!r}")
@@ -276,12 +340,20 @@ def megastep(kind: str, buf: Array, child_ids: Array, ext_ids: Array,
                          f"{weights[0].shape[1]} rows, "
                          f"got {weights[0].shape[0]}")
     bm = block_rows(M)
+    nb, gated = level_extent(M, ext_ids)
+    if gated:
+        # A dead block keeps block 0's mask: no refetch between them.
+        def nm_block(i, c, e, o):
+            return jnp.where(e[M + i] != 0, i, 0), 0, 0
+    else:
+        def nm_block(i, *_):
+            return i, 0, 0
     ws = gate_weights(weights)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(M // bm,),
-        in_specs=[hbm, hbm, pl.BlockSpec((bm, 1, 1), lambda i, *_: (i, 0, 0))]
+        grid=(nb,),
+        in_specs=[hbm, hbm, pl.BlockSpec((bm, 1, 1), nm_block)]
         + [resident(w) for w in ws],
         out_specs=hbm,
         scratch_shapes=[pltpu.VMEM((A, bm, 1, S), buf.dtype),     # children
@@ -291,7 +363,7 @@ def megastep(kind: str, buf: Array, child_ids: Array, ext_ids: Array,
     )
     return pl.pallas_call(
         functools.partial(_megastep_kernel, kind=kind, A=A, bm=bm,
-                          nw=len(ws)),
+                          nw=len(ws), M=M, gated=gated),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         input_output_aliases={3: 0},     # buf (first tensor operand) → out

@@ -42,6 +42,12 @@ The grid is ``(M/bm + 1,)``:
     device sorts.  Callers without a packed schedule (hand-built
     levels) may omit them and pay one ``jnp.argsort`` here.
 
+With the live flags of ``level_megastep.live_ids`` the steps of blocks
+that hold no vertex do nothing (their stash rows are never read: a
+real edge's slot is always real), and the walk stops at the level's
+count of real edges: the sentinel is the largest id, so its run is
+the sorted tail.
+
 Every DMA of the run walk is waited for before the next starts, and
 grid steps run in order, so duplicates are correct by construction
 and deterministic; untouched rows are preserved by the alias.
@@ -85,12 +91,15 @@ def sorted_runs(ids: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
 
 
 def _sorted_run_add(out_ref, src_row: Callable, sid_ref, perm_ref, head_ref,
-                    n: int, live: Callable, acc, row, sem) -> None:
+                    n: int, live: Callable, acc, row, sem,
+                    count=None) -> None:
     """Add ``n`` contribution rows into ``out_ref`` (row layout) in
     sorted-destination order: each run of equal destinations is read
     once, accumulated in VMEM and written once.  ``src_row(p)`` is the
     ref view of contribution ``p``'s row; destinations ``d`` with
-    ``live(d)`` false are skipped."""
+    ``live(d)`` false are skipped.  ``count`` (a traced scalar) stops
+    the walk after the first ``count`` sorted entries, where the rest
+    are known to be skipped."""
     def body(k, carry):
         d = sid_ref[k]
 
@@ -111,7 +120,7 @@ def _sorted_run_add(out_ref, src_row: Callable, sid_ref, perm_ref, head_ref,
 
         return carry
 
-    jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, n if count is None else count, body, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -171,15 +180,21 @@ def scatter_add_rows(dst: jax.Array, idx: jax.Array, rows: jax.Array, *,
 def _bwd_megastep_kernel(cids_ref, eids_ref, off_ref, sid_ref, perm_ref,
                          head_ref, buf_ref, g_ref, ext_ref, nm_ref, *rest,
                          kind: str, A: int, bm: int, nb: int, n: int,
-                         sentinel: int, nw: int):
+                         sentinel: int, nw: int, M: int, gated: bool):
     del g_ref  # read and written through the aliased output
     w_refs = rest[:nw]
     out_ref, stash_ref, chd, exv, gs, gch, acc, row, sem = rest[nw:]
     i = pl.program_id(0)
     S = gs.shape[-1]
+    cotangent_step = i < nb
+    if gated:
+        # A block with no real vertex has no real edge: its stash rows
+        # are never read, so it gathers, computes and writes nothing.
+        cotangent_step = jnp.logical_and(
+            cotangent_step, eids_ref[M + jnp.minimum(i, nb - 1)] != 0)
 
     # -- steps [0, nb): one block of bm slots → child cotangent tiles ---
-    @pl.when(i < nb)
+    @pl.when(cotangent_step)
     def _cotangents():
         m0 = i * bm
         for a in range(A):
@@ -209,13 +224,15 @@ def _bwd_megastep_kernel(cids_ref, eids_ref, off_ref, sid_ref, perm_ref,
                         sem.at[2])
 
     # -- step nb: sorted-run scatter-add of the level's real edges -----
+    # The sentinel is the largest id, so the sorted walk ends in its
+    # run; with the flags it stops at the level's count of real edges.
     @pl.when(i == nb)
     def _scatter():
         _sorted_run_add(
             out_ref,
             lambda p: stash_ref.at[jax.lax.rem(p, A), jax.lax.div(p, A)],
             sid_ref, perm_ref, head_ref, n, lambda d: d != sentinel,
-            acc, row, sem.at[3])
+            acc, row, sem.at[3], count=eids_ref[M + nb] if gated else None)
 
 
 def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
@@ -242,6 +259,10 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
     computes them host-side with the rest of the schedule, so a training
     step pays ZERO on-device sorts.  When omitted (hand-built levels)
     they are derived here with one ``jnp.argsort``.
+
+    ``ext_ids`` may be a level's row of ``level_megastep.live_ids``:
+    blocks with no real vertex are then skipped, and the walk stops at
+    the level's count of real edges.
     """
     M, A = child_ids.shape
     S = g.shape[-1]
@@ -251,15 +272,22 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
         sort_perm, sorted_child_ids, run_head = sorted_runs(
             child_ids.reshape(-1))
     bm = lm.block_rows(M)
-    nb = M // bm
+    nb, gated = lm.level_extent(M, ext_ids)
+    if gated:
+        # Dead blocks, and the walk's step, keep block 0's mask.
+        def nm_block(i, c, e, *_):
+            j = jnp.minimum(i, nb - 1)
+            return jnp.where(e[M + j] != 0, j, 0), 0, 0
+    else:
+        def nm_block(i, *_):
+            return jnp.minimum(i, nb - 1), 0, 0
     ws = lm.gate_weights(weights)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(nb + 1,),
         in_specs=[hbm, hbm, hbm,
-                  pl.BlockSpec((bm, 1, 1),
-                               lambda i, *_: (jnp.minimum(i, nb - 1), 0, 0))]
+                  pl.BlockSpec((bm, 1, 1), nm_block)]
         + [lm.resident(w) for w in ws],
         out_specs=[hbm, hbm],
         scratch_shapes=[pltpu.VMEM((A, bm, 1, S), buf.dtype),     # children
@@ -272,7 +300,8 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
     )
     out, _ = pl.pallas_call(
         functools.partial(_bwd_megastep_kernel, kind=kind, A=A, bm=bm,
-                          nb=nb, n=n, sentinel=sentinel, nw=len(ws)),
+                          nb=nb, n=n, sentinel=sentinel, nw=len(ws), M=M,
+                          gated=gated),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(g.shape, g.dtype),
                    jax.ShapeDtypeStruct((A, M, 1, S), jnp.float32)),

@@ -104,6 +104,10 @@ def level_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
     buffer aliased input→output; the fallback is the op-by-op oracle in
     ``ref.py`` (same math, same contiguous-block write, no fusion
     guarantee).
+
+    ``ext_ids`` is ``[M]``, or a level's row of
+    ``level_megastep.live_ids``: the kernel then skips the blocks that
+    hold no vertex, and the fallbacks read its first ``M`` ids.
     """
     impl = _default_impl() if impl == "auto" else impl
     _tick("level_megastep", impl)
@@ -111,8 +115,9 @@ def level_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
         return lm.megastep(kind, buf, child_ids, ext_ids, node_mask, offset,
                            ext, weights, interpret=_interpret())
     return lm.as_rows(ref.level_megastep(
-        kind, lm.from_rows(buf), child_ids, child_mask, ext_ids, node_mask,
-        offset, lm.from_rows(ext), weights))
+        kind, lm.from_rows(buf), child_ids, child_mask,
+        ext_ids[:child_ids.shape[0]], node_mask, offset, lm.from_rows(ext),
+        weights))
 
 
 def frontier_megastep(kind: str, buf: jax.Array, child_ids: jax.Array,
@@ -184,6 +189,8 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
     precomputed sorted runs (``pack_batch`` host-side output, carried in
     ``DeviceSchedule``) — when given, the pallas backend runs no device
     sort; the jnp fallbacks don't need them and ignore them.
+
+    ``ext_ids`` takes the live flags as for :func:`level_megastep`.
     """
     impl = _default_impl() if impl == "auto" else impl
     _tick("bwd_megastep", impl)
@@ -194,6 +201,7 @@ def bwd_megastep(kind: str, g: jax.Array, buf: jax.Array,
                                 sort_perm=sort_perm,
                                 sorted_child_ids=sorted_child_ids,
                                 run_head=run_head, interpret=_interpret())
+    ext_ids = ext_ids[:child_ids.shape[0]]
     g, buf, ext = lm.from_rows(g), lm.from_rows(buf), lm.from_rows(ext)
     if impl == "ref":
         return lm.as_rows(ref.bwd_megastep(kind, g, buf, child_ids,

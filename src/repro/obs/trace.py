@@ -135,6 +135,11 @@ class SpanHandle:
         self.end()
         return False
 
+    def set(self, **attrs: Any) -> None:
+        """Attach attributes known only inside the span; they are
+        recorded when it ends."""
+        self.attrs = {**(self.attrs or {}), **attrs}
+
     def end(self, **extra: Any) -> None:
         if not self._open:
             self._tracer.double_ends += 1

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from repro.core.structure import (DeviceSchedule, InputGraph, LevelSchedule,
                                   pack_external)
 from repro.dist.fault import chaos_corrupt_ext
+from repro.kernels.level_megastep import block_live
 from repro.obs import trace
 from repro.obs.registry import get_registry
 from repro.pipeline.buckets import BucketPolicy, PadDims, ShapeCensus
@@ -132,11 +133,16 @@ class SchedulePipeline:
         seq = self.pack_seq
         self.pack_seq += 1
         with trace.correlate(batch=seq), \
-                trace.span("pipeline.pack", graphs=len(graphs)):
+                trace.span("pipeline.pack", graphs=len(graphs)) as sp:
             with trace.span("sched.lookup"):
                 sched, dev = self.cache.get_or_pack_device(
                     graphs, pads, with_runs=self.with_runs)
             self.census.record(sched)
+            if sp is not None:
+                # The megastep kernels' grid blocks, and those that
+                # hold a real vertex (the rest are skipped).
+                live = block_live(sched.node_mask)
+                sp.set(blocks=int(live.size), live_blocks=int(live.sum()))
             with trace.span("ext.pack"):
                 ext_np = pack_external(inputs, sched, self.ext_dim)
             # Chaos NaN-batch injection point (identity without a

@@ -254,6 +254,100 @@ def test_treefc_megastep_kernel_matches_ref(seed, m, h, a):
 
 
 # ---------------------------------------------------------------------------
+# Live-block flags: the kernels skip the blocks that hold no vertex
+# ---------------------------------------------------------------------------
+
+def padded_level(kind, lanes, seed=31, h=4):
+    """One level of ``M=384`` slots (three grid blocks of 128) at
+    ``t=1`` of a ``T=3`` buffer, as the scheduler leaves it before the
+    level runs: the level's rows still zero.  ``lanes``: ``"partial"``
+    (100 real lanes from lane 0, children in earlier levels, duplicates
+    and sentinel children among them: two dead blocks), ``"empty"`` (a
+    fully padded level) or ``"leaves"`` (100 real lanes without
+    children: no real edge).  ``kind="dag"`` is the 3-ary Tree-LSTM
+    with duplicate ids across lanes and within one."""
+    rng = np.random.default_rng(seed)
+    cell = "treelstm" if kind == "dag" else kind
+    a = {"lstm": 1, "gru": 1, "treelstm": 2, "treefc": 2, "dag": 3}[kind]
+    smult = {"lstm": 2, "treelstm": 2, "gru": 1, "treefc": 1}[cell]
+    gmult = {"lstm": 4, "treelstm": 4, "gru": 3, "treefc": 1}[cell]
+    S, G = smult * h, gmult * h
+    T, M, t = 3, 384, 1
+    assert lm.block_rows(M) == 128
+    sentinel = T * M
+    buf = rng.standard_normal((T * M + 1, S)).astype(np.float32)
+    buf[t * M:(t + 1) * M] = 0.0
+    buf[sentinel] = 0.0
+    g = rng.standard_normal((T * M + 1, S)).astype(np.float32)
+    real = 0 if lanes == "empty" else 100
+    nm = np.zeros((M,), np.float32)
+    nm[:real] = 1.0
+    cids = np.full((M, a), sentinel, np.int32)
+    if lanes == "partial":
+        cids[:real] = rng.integers(0, t * M, size=(real, a))
+        cids[60] = cids[3]                      # duplicates across lanes
+        cids[7, -1] = cids[90, 0] = sentinel    # absent children
+        if kind == "dag":
+            cids[20, 1] = cids[20, 0]           # ... and within one lane
+    eids = rng.integers(0, 20, size=(M,)).astype(np.int32)
+    ext = rng.standard_normal((21, G)).astype(np.float32)
+    if cell in ("lstm", "gru"):
+        ws = (rng.standard_normal((h, G)) * 0.3, rng.standard_normal(G) * 0.1)
+    elif cell == "treelstm":
+        ws = tuple(rng.standard_normal((h, h)) * 0.3 for _ in range(4)) \
+            + (rng.standard_normal(4 * h) * 0.1,)
+    else:
+        ws = (rng.standard_normal((a * h, h)) * 0.3,
+              rng.standard_normal(h) * 0.1)
+    ids = lm.live_ids(jnp.asarray(eids)[None], jnp.asarray(nm)[None],
+                      jnp.asarray(cids)[None], sentinel)[0]
+    return dict(
+        kind=cell, buf=jnp.asarray(buf), g=jnp.asarray(g),
+        cids=jnp.asarray(cids), cmask=jnp.asarray(cids != sentinel,
+                                                  jnp.float32),
+        eids=jnp.asarray(eids), ids=ids, nm=jnp.asarray(nm), off=t * M,
+        ext=jnp.asarray(ext), ws=tuple(jnp.asarray(w, jnp.float32)
+                                       for w in ws))
+
+
+def test_live_ids_layout():
+    """Ext ids, then one flag per grid block, then the real edges."""
+    lv = padded_level("treelstm", "partial")
+    ids = np.asarray(lv["ids"])
+    np.testing.assert_array_equal(ids[:384], np.asarray(lv["eids"]))
+    np.testing.assert_array_equal(ids[384:387], [1, 0, 0])
+    assert ids[387] == int(np.sum(np.asarray(lv["cids"]) != 3 * 384))
+    assert lm.level_extent(384, lv["ids"]) == (3, True)
+    assert lm.level_extent(384, lv["eids"]) == (3, False)
+    with pytest.raises(ValueError, match="live flags"):
+        lm.level_extent(384, lv["ids"][:-1])
+
+
+@pytest.mark.parametrize("lanes", ["partial", "empty"])
+@pytest.mark.parametrize("kind", ["lstm", "gru", "treelstm", "treefc"])
+def test_megastep_skips_dead_blocks(kind, lanes):
+    """With the live flags the forward writes the live block and leaves
+    the dead ones as they were (zeros): bit-identical to the flag-less
+    kernel, which writes them as ``state·0``, and equal to the oracle."""
+    lv = padded_level(kind, lanes)
+    rows = lm.as_rows
+
+    def run(ids):
+        return np.asarray(lm.from_rows(lm.megastep(
+            lv["kind"], rows(lv["buf"]), lv["cids"], ids, lv["nm"],
+            jnp.int32(lv["off"]), rows(lv["ext"]), lv["ws"],
+            interpret=True)))
+
+    flagged, plain = run(lv["ids"]), run(lv["eids"])
+    np.testing.assert_array_equal(flagged, plain)
+    out_r = ref.level_megastep(lv["kind"], lv["buf"], lv["cids"],
+                               lv["cmask"], lv["eids"], lv["nm"], lv["off"],
+                               lv["ext"], lv["ws"])
+    np.testing.assert_allclose(flagged, np.asarray(out_r), rtol=2e-6,
+                               atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
 # Pallas scatter-add backward (level_megastep_bwd) vs jnp reverse sweep
 # ---------------------------------------------------------------------------
 

@@ -50,6 +50,7 @@ from repro.kernels import level_megastep_bwd as lmb
 from repro.kernels import ref
 from repro.models.rnn import GRUVertex, LSTMVertex
 from repro.models.treelstm import TreeFCVertex, TreeLSTMVertex
+from tests.test_megastep import padded_level
 
 KINDS = ["lstm", "gru", "treelstm", "treefc", "dag"]
 
@@ -355,6 +356,34 @@ def test_megastep_kernels_span_several_blocks(kind):
                              jnp.asarray(ext), ws)
     np.testing.assert_allclose(np.asarray(bwd), np.asarray(bwd_r),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind,lanes", [
+    ("lstm", "partial"), ("gru", "partial"), ("treelstm", "partial"),
+    ("treefc", "partial"), ("dag", "partial"), ("treelstm", "empty"),
+    ("gru", "empty"), ("treelstm", "leaves"), ("lstm", "leaves")])
+def test_bwd_megastep_skips_dead_blocks(kind, lanes):
+    """With the live flags the reverse step skips the dead blocks and
+    walks only the level's real edges (none on a leaf level): the
+    result is the flag-less kernel's, bit for bit, and the oracle's."""
+    lv = padded_level(kind, lanes)
+    rows = lm.as_rows
+
+    def run(ids):
+        return np.asarray(lm.from_rows(lmb.bwd_megastep(
+            lv["kind"], rows(lv["g"]), rows(lv["buf"]), lv["cids"], ids,
+            lv["nm"], jnp.int32(lv["off"]), rows(lv["ext"]), lv["ws"],
+            interpret=True)))
+
+    flagged, plain = run(lv["ids"]), run(lv["eids"])
+    np.testing.assert_array_equal(flagged, plain)
+    out_r = ref.bwd_megastep(lv["kind"], lv["g"], lv["buf"], lv["cids"],
+                             lv["cmask"], lv["eids"], lv["nm"], lv["off"],
+                             lv["ext"], lv["ws"])
+    np.testing.assert_allclose(flagged, np.asarray(out_r), rtol=2e-5,
+                               atol=2e-5)
+    if lanes != "partial":
+        np.testing.assert_array_equal(flagged, np.asarray(lv["g"]))
 
 
 # ---------------------------------------------------------------------------

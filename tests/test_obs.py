@@ -287,6 +287,23 @@ def test_pipeline_spans_and_cache_hit_instants():
     assert batches == {0, 1}
 
 
+def test_pipeline_pack_span_counts_live_blocks():
+    """``pipeline.pack`` carries the megastep kernels' grid blocks and
+    those holding a real vertex.  Chains of 5, 3 and 4 packed 8 levels
+    deep and 200 wide: blocks of 100 slots (the largest divisor of 200
+    up to 128), two a level, 16 in all; the chains fill lanes 0-2 of
+    levels 0-4, so 5 blocks are live."""
+    from repro.pipeline.buckets import PadDims
+    graphs = [chain(5), chain(3), chain(4)]
+    inputs = [np.zeros((g.num_nodes, INPUT_DIM), np.float32) for g in graphs]
+    t = Tracer()
+    with trace.install_tracer(t):
+        SchedulePipeline(ext_dim=INPUT_DIM).pack(
+            graphs, inputs, pads=PadDims(8, 200, None, None))
+    (sp,) = [sp for sp in t.snapshot() if sp.name == "pipeline.pack"]
+    assert sp.attrs == {"graphs": 3, "blocks": 16, "live_blocks": 5}
+
+
 # ---------------------------------------------------------------------------
 # MetricsRegistry
 # ---------------------------------------------------------------------------

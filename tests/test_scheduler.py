@@ -213,3 +213,57 @@ def test_graph_rnn_dags_batched_equals_serial(seed):
     for k, g in enumerate(graphs):
         np.testing.assert_allclose(nodes[k, : g.num_nodes], serial[k],
                                    rtol=2e-5, atol=2e-5)
+
+
+def test_padded_megastep_skips_dead_blocks(monkeypatch):
+    """Tree-LSTM batches padded 4x wide and 2x deep, as buckets pad
+    them: the fused path's kernels (pallas, interpret mode) skip the
+    blocks and levels that hold no vertex, yet the loss and gradients
+    of ``execute_lazy`` match the op-by-op path, and its forward buffer
+    is a scan of the flag-less kernel's, bit for bit."""
+    from repro.core.structure import random_binary_tree
+    from repro.core.vertex import get_gate_spec
+    from repro.kernels import level_megastep as lm
+
+    rng = np.random.default_rng(3)
+    fn = TreeLSTMVertex(input_dim=4, hidden=4, arity=2)
+    graphs = [random_binary_tree(n, rng) for n in (12, 9, 14)]
+    tight = pack_batch(graphs, pad_arity=2)
+    sched = pack_batch(graphs, pad_levels=2 * tight.T,
+                       pad_width=4 * tight.M, pad_arity=2)
+    dev = sched.to_device()
+    T, M = sched.T, sched.M
+    live = lm.block_live(sched.node_mask)
+    assert live.shape[1] > 1 and 0 < live.sum() <= live.size // 4
+    params = fn.init(jax.random.PRNGKey(3))
+    inputs = [rng.standard_normal((g.num_nodes, 4)).astype(np.float32)
+              * 0.3 for g in graphs]
+    ext = jnp.asarray(pack_external(inputs, sched, 4))
+
+    def loss(p, e, mode):
+        buf = execute_lazy(fn, p, e, dev, fusion_mode=mode)
+        return jnp.sum(readout_nodes(buf, dev) ** 2) \
+            + jnp.sum(readout_roots(buf, dev) ** 3)
+
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    l_fu, g_fu = jax.value_and_grad(loss, (0, 1))(params, ext, "megastep")
+    buf_fu = execute_lazy(fn, params, ext, dev, fusion_mode="megastep")
+    l_un, g_un = jax.value_and_grad(loss, (0, 1))(params, ext, "none")
+    np.testing.assert_allclose(float(l_fu), float(l_un), rtol=1e-5)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5), g_fu, g_un)
+
+    spec = get_gate_spec(fn)
+    ext_rows = lm.as_rows(fn.project_inputs(params, ext))
+
+    def step(buf, xs):
+        t, cids, eids, nm = xs
+        return lm.megastep(spec.kind, buf, cids, eids, nm, t * M, ext_rows,
+                           spec.weights(params), interpret=True), None
+
+    plain, _ = jax.lax.scan(
+        step, jnp.zeros((T * M + 1, 1, spec.state_dim), jnp.float32),
+        (jnp.arange(T, dtype=jnp.int32), dev.child_ids, dev.ext_ids,
+         dev.node_mask))
+    np.testing.assert_array_equal(np.asarray(buf_fu),
+                                  np.asarray(lm.from_rows(plain)))

@@ -13,6 +13,7 @@ import: only one process at a time may load the TPU library, and the
 fixture skips where no topology can be described.
 """
 
+import importlib.util
 import os
 
 import jax
@@ -106,6 +107,62 @@ def test_backward_megastep_compiles(kind, one_chip):
         sd((n,), jnp.int32), sd((n,), jnp.int32), sd((n,), jnp.int32),
         *[sd(w) for w in ws])
     assert "tpu_custom_call" in txt
+
+
+#: (M, A, T) of the training cells' widest buckets with the live flags:
+#: Tree-LSTM on SST-length parses (M=4096), LSTM on PTB chains (M=64).
+FLAGGED = {"treelstm": (4096, 2, 32), "lstm": (64, 1, 64)}
+
+
+def _trace_reduce():
+    """The benchmark's trace reader, loaded from its file."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "trace_reduce.py")
+    spec = importlib.util.spec_from_file_location("trace_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _kernel_names(compiled):
+    """What ``trace_reduce.kernel_of`` makes of each Pallas call of a
+    compiled program, printed with its operands' shapes as the chip's
+    trace names operations."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    tr = _trace_reduce()
+    return [tr.kernel_of(line) for line in text.splitlines()
+            if "tpu_custom_call" in line]
+
+
+@pytest.mark.parametrize("kind", sorted(FLAGGED))
+def test_megasteps_with_live_flags_compile(kind, one_chip):
+    """Both megasteps with the live flags appended to the ext ids (the
+    backward's walk then has a dynamic trip count) compile for the chip,
+    and the benchmark still tells them apart by their operands."""
+    M, A, T = FLAGGED[kind]
+    S, G, ws = _dims(kind, A)
+    R, n = T * M + 1, M * A
+    L = M + M // lm.block_rows(M) + 1
+    sd = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    fwd = jax.jit(lambda buf, c, e, nm, off, ext, *w: lm.megastep(
+        kind, buf, c, e, nm, off, ext, w)).lower(
+        sd((R, 1, S)), sd((M, A), jnp.int32), sd((L,), jnp.int32),
+        sd((M,)), sd((), jnp.int32), sd((R, 1, G)),
+        *[sd(w) for w in ws]).compile()
+    assert _kernel_names(fwd) == ["_megastep_kernel"]
+    bwd = jax.jit(
+        lambda g, buf, c, e, nm, off, ext, sp, sc, rh, *w: lmb.bwd_megastep(
+            kind, g, buf, c, e, nm, off, ext, w, sort_perm=sp,
+            sorted_child_ids=sc, run_head=rh)).lower(
+        sd((R, 1, S)), sd((R, 1, S)), sd((M, A), jnp.int32),
+        sd((L,), jnp.int32), sd((M,)), sd((), jnp.int32), sd((R, 1, G)),
+        sd((n,), jnp.int32), sd((n,), jnp.int32), sd((n,), jnp.int32),
+        *[sd(w) for w in ws]).compile()
+    assert _kernel_names(bwd) == ["_bwd_megastep_kernel"]
 
 
 def test_scatter_add_rows_compiles(one_chip):
