@@ -100,6 +100,37 @@ class VertexOutput:
     push: Optional[Array] = None
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class ArenaIO:
+    """What one frontier tick of a vertex whose state stays in the
+    arena sees (chains only): each lane is one vertex that updates its
+    chain's state row in place, plus the key/value rows of every
+    earlier vertex of its chain.
+
+    A vertex function that declares ``apply_arena(params, arena, io)``
+    gets this instead of :class:`VertexIO`: its state row (every
+    layer's recurrent state, megabytes a vertex) is never gathered into
+    an ``[M, S]`` batch.  ``arena`` is the engine's pytree of pools
+    (``init_arena``); the function returns it updated.
+    """
+
+    #: ``[M]`` int32 the chain's state row: the lane reads its
+    #: predecessor's state there and writes its own over it (the
+    #: arena's spare row on pad lanes).  The lane's push goes to the
+    #: same row of the push buffer.
+    rows: Array
+    #: ``[M]`` int32: 0 pad lane, 1 first vertex of its chain (no
+    #: predecessor: its row is not read), 2 a vertex with a predecessor.
+    flags: Array
+    #: ``[M]`` int32 the vertex's raw input (a token id).
+    tokens: Array
+    #: ``[M]`` int32 index of the vertex along its chain.
+    position: Array
+    #: ``[M, B]`` int32 the chain's key/value blocks, in order.
+    kv_blocks: Array
+
+
 @dataclasses.dataclass(frozen=True)
 class GateSpec:
     """A cell's declaration that its gate math is *megastep-fusable*.

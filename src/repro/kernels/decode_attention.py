@@ -160,3 +160,34 @@ def decode_attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (m, l, acc), _ = jax.lax.scan(k_step, st0, (jnp.arange(nk), ks, vs))
     l = jnp.where(l == 0.0, 1.0, l)
     return (acc / l[..., None]).astype(q.dtype)
+
+
+def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, block_table: jax.Array,
+                           kv_len: jax.Array, *,
+                           scale: Optional[float] = None) -> jax.Array:
+    """Single-token GQA attention over a paged cache, plain jnp.
+
+    ``q``: ``[M, Hq, D]``; ``k_pool``/``v_pool``: ``[blocks, bt, Hkv*D]``
+    (a row a token, ``bt`` tokens a block); ``block_table``: ``[M, nb]``
+    each lane's blocks in order (pad entries point at any block);
+    ``kv_len``: ``[M]`` valid tokens a lane.  Positions at or past
+    ``kv_len`` get no weight, whatever their rows hold.  Each lane's
+    blocks are gathered whole (``nb·bt`` rows), the price of a jnp path;
+    a Pallas twin would stream only the blocks below ``kv_len``."""
+    M, Hq, D = q.shape
+    nb = block_table.shape[1]
+    bt = k_pool.shape[1]
+    Hkv = k_pool.shape[2] // D
+    g = Hq // Hkv
+    scale = scale if scale is not None else D ** -0.5
+    valid = jnp.arange(nb * bt)[None, :] < kv_len[:, None]      # [M, T]
+    k = k_pool[block_table].reshape(M, nb * bt, Hkv, D)
+    v = jnp.where(valid[:, :, None, None],
+                  v_pool[block_table].reshape(M, nb * bt, Hkv, D), 0.0)
+    qg = q.reshape(M, Hkv, g, D).astype(jnp.float32)
+    s = jnp.einsum("mhgd,mthd->mhgt", qg, k.astype(jnp.float32)) * scale
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("mhgt,mthd->mhgd", p, v.astype(jnp.float32))
+    return o.reshape(M, Hq, D).astype(q.dtype)

@@ -335,3 +335,34 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 
 def ssd_decode_step(x, dt, A, B, C, D, state):
     return ref.ssd_decode_step(x, dt, A, B, C, D, state)
+
+
+def ssm_step(arena: jax.Array, layer: int, rows: jax.Array,
+             flags: jax.Array, xbc: jax.Array, dt: jax.Array,
+             conv_w: jax.Array, conv_b: jax.Array, a: jax.Array,
+             d: jax.Array, impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
+    """One token of every frontier lane through a Mamba-2 layer's conv
+    and state update, in the state arena: each lane updates its chain's
+    row in place (``kernels/ssm_step.py``).  The pallas backend is one
+    launch named ``ssm_step`` with the arena aliased in place; the
+    fallback gathers and scatters the rows op by op."""
+    impl = _default_impl() if impl == "auto" else impl
+    _tick("ssm_step", impl)
+    from repro.kernels import ssm_step as ks
+    if impl == "pallas":
+        return ks.ssm_step(arena, layer, rows, flags, xbc, dt, conv_w,
+                           conv_b, a, d, interpret=_interpret())
+    return ks.ssm_step_ref(arena, layer, rows, flags, xbc, dt, conv_w,
+                           conv_b, a, d)
+
+
+def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, block_table: jax.Array,
+                           kv_len: jax.Array, *,
+                           scale: Optional[float] = None) -> jax.Array:
+    """``[M, Hq, D]`` queries over each lane's blocks of a paged K/V
+    pool (``decode_attention.paged_decode_attention``; jnp on every
+    backend)."""
+    _tick("paged_decode_attention", "chunked")
+    return dec.paged_decode_attention(q, k_pool, v_pool, block_table,
+                                      kv_len, scale=scale)

@@ -9,12 +9,13 @@ from repro.serve.engine import (Request, ServeEngine, StructureRequest,
                                 StructureServeEngine, VertexRequest,
                                 VertexServeEngine)
 from repro.serve.continuous import (AdmissionPolicy, ContinuousBatchEngine,
-                                    ContinuousRequest)
+                                    ContinuousRequest, HybridChainEngine)
 from repro.serve.robustness import (CircuitBreaker, RequestLifecycle,
                                     TERMINAL, quarantine_bisect)
 
 __all__ = ["CacheSlots", "Request", "ServeEngine", "StructureRequest",
            "StructureServeEngine", "VertexRequest", "VertexServeEngine",
            "AdmissionPolicy", "ContinuousBatchEngine", "ContinuousRequest",
+           "HybridChainEngine",
            "CircuitBreaker", "RequestLifecycle", "TERMINAL",
            "quarantine_bisect"]

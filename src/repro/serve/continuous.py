@@ -65,7 +65,7 @@ import numpy as np
 
 from repro.core.scheduler import frontier_step, resolve_fusion
 from repro.core.structure import InputGraph, LevelSchedule
-from repro.core.vertex import has_eager_projection
+from repro.core.vertex import ArenaIO, has_eager_projection
 from repro.dist.fault import chaos_corrupt_ext, chaos_fire
 from repro.kernels.level_megastep import as_rows, from_rows
 from repro.models.readout import ClassificationHead, TokenReadout
@@ -265,9 +265,9 @@ class ContinuousBatchEngine(_EngineBase):
         # Arena: rows [0, num_rows) are allocatable; row num_rows is the
         # zero sentinel absent children gather (it is never written —
         # pad lanes scatter out of range and are dropped).
-        S = fn.state_dim
-        self._buf = jnp.zeros((num_rows + 1, S), jnp.float32)
-        self._arena_bytes = int(self._buf.nbytes)   # one whole readback
+        self._buf = self._init_arena()
+        self._arena_bytes = int(sum(x.nbytes for x in
+                                    jax.tree.leaves(self._buf)))
         self._free: List[int] = list(range(num_rows - 1, -1, -1))
         self._active: List[_Active] = []
         self._project = (jax.jit(fn.project_inputs)
@@ -354,8 +354,7 @@ class ContinuousBatchEngine(_EngineBase):
         if ticks:
             with trace.span("cb.stack", ticks=len(ticks)):
                 args = self._stack_window(ticks)
-            lanes = (sum(len(p[0]) for parts in ticks for p in parts)
-                     if trace.enabled() else 0)
+            lanes = self._tick_lanes(ticks) if trace.enabled() else 0
             try:
                 with trace.span("cb.window", ticks=len(ticks),
                                 fused=self.fused, lanes=lanes):
@@ -398,7 +397,7 @@ class ContinuousBatchEngine(_EngineBase):
                 self.lifecycle.finish_failed(req, f"schedule pack "
                                                   f"failed: {e}")
                 continue
-            if plan.num_rows > len(self._free):
+            if not self._fits(plan):
                 break
             self.queue.pop(0)
             try:
@@ -410,6 +409,12 @@ class ContinuousBatchEngine(_EngineBase):
                 continue
             admitted += 1
         return admitted
+
+    def _init_arena(self):
+        return jnp.zeros((self.num_rows + 1, self.fn.state_dim), jnp.float32)
+
+    def _fits(self, plan) -> bool:
+        return plan.num_rows <= len(self._free)
 
     def _plan_for(self, graph: InputGraph) -> _Plan:
         pads = self._buckets.bucket([graph])._replace(arity=self.A)
@@ -551,6 +556,10 @@ class ContinuousBatchEngine(_EngineBase):
                 jnp.asarray(child_mask), jnp.asarray(ext_rows),
                 jnp.asarray(node_mask), jnp.asarray(out_ids))
 
+    @staticmethod
+    def _tick_lanes(ticks) -> int:
+        return sum(len(p[0]) for parts in ticks for p in parts)
+
     def _ext_width(self) -> int:
         return self.fn.ext_dim
 
@@ -662,3 +671,279 @@ class ContinuousBatchEngine(_EngineBase):
                 "plan_misses": self.plan_misses,
                 "breaker_open": self._breaker.open,
                 "breaker_trips": self._breaker.trips}
+
+
+# ---------------------------------------------------------------------------
+# Chains whose vertex keeps its state in the arena: two pools, one manager
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _ChainPlan:
+    """A chain of ``length`` vertices: one state row and ``kv_blocks``
+    blocks of the key/value pool for its whole life."""
+
+    length: int
+    kv_blocks: int
+    num_rows: int = 1
+
+
+class _ChainActive:
+    """One in-flight chain: its state row, its key/value block table,
+    its token ids and the next vertex to fire."""
+
+    __slots__ = ("req", "length", "pos", "row", "blocks", "tokens")
+
+    def __init__(self, req, length: int, row: int, blocks: List[int],
+                 tokens: np.ndarray):
+        self.req = req
+        self.length = length
+        self.pos = 0
+        self.row = row
+        self.blocks = blocks
+        self.tokens = tokens
+
+    @property
+    def finished(self) -> bool:
+        return self.pos >= self.length
+
+
+def _arena_window(fn, params: Params, arena, io, k: jax.Array):
+    """The first ``k`` ticks of a stacked window (``io`` leaves are
+    ``[max_window, M, ...]``) as one ``fori_loop``: one program serves
+    every window length, and the arena is updated in place.  A tick is
+    the vertex's ``apply_arena`` over the union frontier."""
+
+    def body(i, a):
+        return fn.apply_arena(params, a, jax.tree.map(lambda x: x[i], io))
+
+    return jax.lax.fori_loop(0, k, body, arena)
+
+
+class HybridChainEngine(ContinuousBatchEngine):
+    """Continuous batching of chains (token sequences) whose vertex
+    keeps a large recurrent state and attends over its chain's keys
+    and values: ``fn`` declares ``apply_arena`` (e.g.
+    ``models.granite_hybrid.GraniteHybridVertex``).
+
+    The cache manager holds two pools side by side:
+
+      - **state rows** (``num_rows``, each the vertex's whole recurrent
+        state): a chain holds one, which each of its vertices reads and
+        overwrites in place (vertex ``t`` finds there the state vertex
+        ``t-1`` left); it returns to the free list when the chain
+        retires;
+      - **key/value blocks** (``kv_tokens`` rows a token, in blocks of
+        ``fn.kv_block``): allocated at admission for the chain's whole
+        length and held until it retires.
+
+    Admission is FIFO against both budgets (head-of-line blocking, as
+    for trees).  Freed rows are not zeroed: a chain's first vertex
+    reads no predecessor row, and attention gives no weight to
+    positions past the vertex's own, so nothing a dead request left is
+    ever read.  Each tick fires every unfinished chain's next vertex
+    (at most ``frontier_width``); a window of up to
+    ``policy.max_window`` ticks is one ``fori_loop`` over the stacked
+    lanes with the arena donated.  A lane carries its token id; the
+    embedding lookup runs inside the tick.  Retirement reads the push
+    buffer back (one row a chain: the final-norm hidden state) and
+    computes full-vocabulary logits for the retiring chains.
+    """
+
+    def __init__(self, fn, params: Params, *, num_rows: int = 64,
+                 kv_tokens: int = 64 * 1536, frontier_width: int = 64,
+                 policy: AdmissionPolicy = AdmissionPolicy(), **kw):
+        self.kv_block = fn.kv_block
+        self.num_kv_blocks = kv_tokens // fn.kv_block
+        super().__init__(fn, params, num_rows=num_rows,
+                         frontier_width=frontier_width, fusion_mode="none",
+                         policy=policy, **kw)
+        self._kv_free: List[int] = list(range(self.num_kv_blocks - 1, -1, -1))
+        self._arena_step = jax.jit(functools.partial(_arena_window, fn),
+                                   donate_argnums=(1,))
+        self._logits = jax.jit(fn.logits)
+        #: Sums over ticks of the live state rows and of the pool's rows
+        #: (``cb.state_rows{live, total}``), of the lanes fired, and of
+        #: the lanes that started a chain (no predecessor row read).
+        self.state_rows_live = 0
+        self.state_rows_total = 0
+        self.lanes_fired = 0
+        self.chain_starts = 0
+
+    def _init_arena(self):
+        return self.fn.init_arena(self.num_rows, self.num_kv_blocks)
+
+    @property
+    def free_kv_blocks(self) -> int:
+        return len(self._kv_free)
+
+    # -- ingress and admission ----------------------------------------------
+    def submit(self, req: ContinuousRequest) -> bool:
+        err = validate_structure(req.graph, req.inputs, self.fn.input_dim)
+        n = req.graph.num_nodes
+        if err is None and any(list(ch) != ([t - 1] if t else [])
+                               for t, ch in enumerate(req.graph.children)):
+            err = "structure is not a chain (vertex t reads t - 1)"
+        if err is None and n > self.fn.max_len:
+            err = f"chain of {n} > the vertex's max_len {self.fn.max_len}"
+        if err is None and -(-n // self.kv_block) > self.num_kv_blocks:
+            err = (f"chain needs {-(-n // self.kv_block)} key/value blocks "
+                   f"> the pool's {self.num_kv_blocks}")
+        if err is not None:
+            err = f"request {req.request_id}: {err}"
+        return self.lifecycle.submit(req, err)
+
+    def _plan_for(self, graph: InputGraph) -> _ChainPlan:
+        n = graph.num_nodes
+        return _ChainPlan(length=n, kv_blocks=-(-n // self.kv_block))
+
+    def _fits(self, plan: _ChainPlan) -> bool:
+        return (plan.num_rows <= len(self._free)
+                and plan.kv_blocks <= len(self._kv_free))
+
+    def _activate(self, req: ContinuousRequest, plan: _ChainPlan) -> None:
+        row = self._free.pop()
+        with trace.span("cb.kv_alloc", blocks=plan.kv_blocks,
+                        tokens=plan.length, free=len(self._kv_free)):
+            blocks = [self._kv_free.pop() for _ in range(plan.kv_blocks)]
+        tokens = np.asarray(req.inputs, np.float32)[:, 0].astype(np.int32)
+        req.status = ACTIVE
+        self._active.append(_ChainActive(req, plan.length, row, blocks,
+                                         tokens))
+
+    # -- window planning ------------------------------------------------------
+    def _next_tick_lanes(self) -> int:
+        return min(self.frontier_width,
+                   sum(1 for a in self._active if not a.finished))
+
+    def _plan_window(self, max_ticks: int):
+        """Each tick fires the next vertex of every unfinished chain (up
+        to ``frontier_width``, oldest first); stops at the first tick
+        that finishes a chain.  Ticks are lists of ``(chain, t)``."""
+        ticks, done = [], []
+        for _ in range(max_ticks):
+            lanes = [a for a in self._active
+                     if not a.finished][:self.frontier_width]
+            if not lanes:
+                break
+            ticks.append([(a, a.pos) for a in lanes])
+            for a in lanes:
+                a.pos += 1
+            finished = [a for a in lanes if a.finished]
+            if finished:
+                done.extend(finished)
+                break
+        return ticks, done
+
+    @staticmethod
+    def _tick_lanes(ticks) -> int:
+        return sum(len(lanes) for lanes in ticks)
+
+    def _stack_window(self, ticks) -> Tuple:
+        """``ArenaIO`` leaves ``[max_window, M, ...]`` (pad lanes: the
+        spare row, flag 0) and the tick count."""
+        io = self._idle_io()
+        for k, lanes in enumerate(ticks):
+            for m, (a, t) in enumerate(lanes):
+                io.rows[k, m] = a.row
+                io.flags[k, m] = 2 if t else 1
+                io.tokens[k, m] = a.tokens[t]
+                io.position[k, m] = t
+                io.kv_blocks[k, m, :len(a.blocks)] = a.blocks
+        live = self.num_rows - len(self._free)
+        self.lanes_fired += self._tick_lanes(ticks)
+        self.chain_starts += int((io.flags == 1).sum())
+        self.state_rows_live += live * len(ticks)
+        self.state_rows_total += self.num_rows * len(ticks)
+        trace.instant("cb.state_rows", live=live, total=self.num_rows,
+                      ticks=len(ticks))
+        return (self.params, self._buf, jax.tree.map(jnp.asarray, io),
+                jnp.int32(len(ticks)))
+
+    def _idle_io(self) -> ArenaIO:
+        """A window's host arrays with every lane a pad lane."""
+        K, M = self.policy.max_window, self.frontier_width
+        return ArenaIO(rows=np.full((K, M), self.num_rows, np.int32),
+                       flags=np.zeros((K, M), np.int32),
+                       tokens=np.zeros((K, M), np.int32),
+                       position=np.zeros((K, M), np.int32),
+                       kv_blocks=np.full((K, M, self.fn.max_blocks),
+                                         self.num_kv_blocks, np.int32))
+
+    def _run_window(self, args: Tuple):
+        """No op-by-op rung: the arena is donated to the window, so a
+        failed window loses it; a fresh one is made for the requests
+        still queued, and the failure reaches every in-flight chain."""
+        try:
+            chaos_fire("kernel")
+            out = self._arena_step(*args)
+            with trace.span("cb.wait"):
+                jax.block_until_ready(out)
+            return out
+        except Exception as e:           # noqa: BLE001 — fail in-flight
+            self.lifecycle.degrade(e)
+            self._buf = self._init_arena()
+            raise
+
+    def warm(self) -> None:
+        """Compile every program the engine can dispatch: the window
+        (one, for every length) and the logits for each
+        retirement-count bucket."""
+        self._buf = self._arena_step(self.params, self._buf,
+                                     jax.tree.map(jnp.asarray,
+                                                  self._idle_io()),
+                                     jnp.int32(1))
+        n = 1
+        while n < 2 * self.frontier_width:
+            self._logits(self.params, jnp.zeros((n, self.fn.push_dim)))
+            n *= 2
+        jax.block_until_ready(self._buf)
+
+    # -- retirement -----------------------------------------------------------
+    def _release(self, acts) -> None:
+        gone = set(map(id, acts))
+        self._active = [a for a in self._active if id(a) not in gone]
+        for a in acts:
+            self._free.append(a.row)
+            self._kv_free.extend(a.blocks)
+
+    def _retire(self, done) -> None:
+        """Read the push buffer back (a row a retiring chain) and turn
+        the final-norm hidden states into logits, one batched call
+        padded to a power of two."""
+        push = self._buf["push"]
+        with trace.span("cb.readback", count=len(done),
+                        bytes=int(push.nbytes)):
+            pushed = np.asarray(push)[[a.row for a in done]]
+        ok = []
+        for a, h in zip(done, pushed):
+            req = a.req
+            if self.lifecycle.expired(req):
+                self.lifecycle.finish_timeout(req)
+                status = "timeout"
+            elif self.guard_nonfinite and not np.isfinite(h).all():
+                self.lifecycle.finish_failed(req, "non-finite push")
+                status = "failed"
+            else:
+                req.root_state = h.copy()
+                ok.append(req)
+                status = "ok"
+            trace.instant("cb.retired", request=req.request_id,
+                          status=status)
+        self._release(done)
+        if ok:
+            K = len(ok)
+            batch = np.zeros((1 << (K - 1).bit_length(), self.fn.push_dim),
+                             np.float32)
+            for i, req in enumerate(ok):
+                batch[i] = req.root_state
+            with trace.span("cb.logits", count=K, vocab=self.fn.dims.vocab):
+                logits = np.asarray(self._logits(self.params,
+                                                 jnp.asarray(batch)))
+            for i, req in enumerate(ok):
+                req.logits = logits[i].copy()
+                self.lifecycle.finish_ok(req)
+
+    def _health_extra(self) -> Dict[str, Any]:
+        return {**super()._health_extra(),
+                "free_kv_blocks": self.free_kv_blocks,
+                "num_kv_blocks": self.num_kv_blocks}

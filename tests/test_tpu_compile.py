@@ -199,3 +199,26 @@ def test_gather_rows_compiles(one_chip):
     txt = _compile_text(lambda s, i: gsc.gather_rows(s, i),
                         sd((4097, 1, 2 * H)), sd((256,), jnp.int32))
     assert "tpu_custom_call" in txt
+
+
+def test_ssm_step_compiles(one_chip, monkeypatch):
+    """The Mamba-2 one-token kernel at Granite-4.0-H Micro's published
+    widths (64 heads of 64, d_state 128, conv 4) over a 64-lane frontier
+    and a 9-layer state arena, the arena aliased in place."""
+    from repro.kernels import ssm_step as ks
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    M, R, N, W, K, L = 64, 32, 128, 128, 4, 9
+    blk = R * N + (K - 1) * ks.slot_rows(R + 2)
+    sd = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    compiled = jax.jit(
+        lambda a, rows, fl, x, dt, cw, cb, av, dv: ops.ssm_step(
+            a, 4, rows, fl, x, dt, cw, cb, av, dv, impl="pallas"),
+        donate_argnums=0).lower(
+        sd((65, L, blk, W)), sd((M,), jnp.int32),
+        sd((M,), jnp.int32), sd((M, R + 2, W)), sd((M, R, W)),
+        sd((K, R + 2, W)), sd((R + 2, W)), sd((R, W)), sd((R, W))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the arena is updated in place, never copied
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
