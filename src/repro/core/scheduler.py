@@ -18,9 +18,11 @@ Backward: two modes.
   gradients (the paper's canonical lazy operators: "the math operators
   for computing gradients of the model parameters") are computed **once,
   batched over all vertices of all graphs**, as a single flat VJP over
-  the ``T*M`` node slots, instead of ``T`` per-task VJPs.  As a bonus the
-  forward saves only the node buffer (activations inside ``F`` are
-  recomputed), so this doubles as a rematerialization policy.
+  the node slots instead of ``T`` per-task VJPs (the fused backward
+  walks the node table, not the padded ``T*M`` grid, where the table is
+  the smaller: :func:`_lazy_index`).  As a bonus the forward saves only
+  the node buffer (activations inside ``F`` are recomputed), so this
+  doubles as a rematerialization policy.
 
 The *eager* side of §3.5 (streaming) is ``hoist=True``: when ``F``
 declares ``project_inputs`` (its vertex-independent prefix, e.g. the
@@ -51,7 +53,8 @@ with the gradient buffer aliased in place; off-pallas the jnp
 ``level_bwd`` sweep, which stays the correctness oracle and ablation
 baseline (selectable via ``REPRO_FUSION=none`` /
 ``REPRO_KERNEL_IMPL=chunked``).  The parameter/external gradients are
-computed lazily in one flat batched pass (§3.5) — so both
+computed lazily in one flat batched pass (§3.5) over the smaller of
+the node table and the slot grid — so both
 :func:`execute` and :func:`execute_lazy` share one backward, with
 activations recomputed from the node buffer (remat).  Both scans hand
 the kernels each level's live extent (``megastep.live_ids``, built
@@ -76,6 +79,7 @@ from repro.core.vertex import (GateSpec, VertexFunction, VertexIO,
                                get_gate_spec, has_eager_projection)
 from repro.kernels import level_megastep as megastep
 from repro.kernels import ops as kops
+from repro.obs.registry import get_registry
 
 Params = Any
 Array = jax.Array
@@ -278,24 +282,36 @@ def _megastep_bwd(fn, res, g_buf):
     # Row t*M+m reaches its final value before level t's reverse step
     # runs (all its parents live at levels > t), so the swept buffer IS
     # the per-slot state cotangent — no per-level stacking needed.
-    g_state_flat = megastep.from_rows(g_final)[: T * M] \
-        * sched.node_mask.reshape(T * M)[:, None].astype(g_final.dtype)
+    # Lazy batching: one analytic pass for the parameter and pulled-row
+    # gradients, over the rows of ``_lazy_index`` (``None``: every slot).
+    rows, valid = _lazy_index(sched)
+    K, N = sched.slot_of.shape
 
-    # Lazy batching: one analytic pass over ALL T*M slots for the
-    # parameter and pulled-row gradients.
-    cid_flat = sched.child_ids.reshape(T * M, A)
-    child_flat = jnp.take(buf, cid_flat.reshape(-1),
-                          axis=0).reshape(T * M, A, S)
-    rows_flat = jnp.take(ext, sched.ext_ids.reshape(T * M), axis=0)
-    cmask_flat = sched.child_mask.reshape(T * M, A)
-    _, d_gates, aux = megastep.level_bwd(spec.kind, g_state_flat, child_flat,
-                                         rows_flat, cmask_flat, weights)
+    def pick(x, fill):
+        """The pass's rows of a ``[T*M, ...]`` array; the node table's
+        sentinel entries (row ``T*M``) read ``fill``."""
+        if rows is None:
+            return x
+        return jnp.take(x, rows, axis=0, mode="fill", fill_value=fill)
+
+    if rows is None:
+        g_state = megastep.from_rows(g_final)[: T * M]
+    else:   # taken before the reshape: no slice of the whole buffer
+        g_state = megastep.from_rows(jnp.take(g_final, rows, axis=0))
+    g_state = g_state * valid[:, None].astype(g_final.dtype)
+    cid = pick(sched.child_ids.reshape(T * M, A), T * M)
+    child = jnp.take(buf, cid.reshape(-1), axis=0).reshape(-1, A, S)
+    ext_ids = pick(sched.ext_ids.reshape(T * M), K * N)
+    ext_in = jnp.take(ext, ext_ids, axis=0)
+    cmask = pick(sched.child_mask.reshape(T * M, A), 0)
+    _, d_gates, aux = megastep.level_bwd(spec.kind, g_state, child,
+                                         ext_in, cmask, weights)
     w_grads = megastep.level_param_grads(spec.kind, d_gates, aux, weights)
     g_params = spec.inject_grads(params, w_grads)
 
     # ∂pull = push: scatter row cotangents back to the packed matrix,
     # then run the hoisted projection's VJP once.
-    g_ext = jnp.zeros_like(ext).at[sched.ext_ids.reshape(T * M)].add(
+    g_ext = jnp.zeros_like(ext).at[ext_ids].add(
         d_gates.astype(ext.dtype), mode="drop")
     g_params_hoist, g_external = hoist_vjp(g_ext)
     g_params = jax.tree.map(jnp.add, g_params, g_params_hoist)
@@ -304,6 +320,27 @@ def _megastep_bwd(fn, res, g_buf):
 
 
 _execute_megastep.defvjp(_megastep_fwd, _megastep_bwd)
+
+
+def _lazy_index(sched: DeviceSchedule) -> Tuple[Optional[Array], Array]:
+    """The rows the fused backward's lazy pass runs over, and their mask.
+
+    Every real vertex holds one slot of the ``T*M`` grid, and
+    ``slot_of [K, N]`` names each once (its sentinel entries point at
+    row ``T*M`` and are masked by ``node_valid``).  Bucketed trees leave
+    most slots empty, so where the node table is the smaller index
+    (``K*N < T*M``, static shapes) the pass walks it: the same products
+    over the same real rows, without the padding.  Otherwise it walks
+    the whole grid in place (``rows`` is ``None``, ``node_mask``
+    masks).  Counted at trace time, once per compiled backward
+    (``sched.lazy_pass{index=nodes|slots}``)."""
+    T, M = sched.T, sched.M
+    K, N = sched.slot_of.shape
+    if K * N < T * M:
+        get_registry().inc("sched.lazy_pass", index="nodes")
+        return sched.slot_of.reshape(K * N), sched.node_valid.reshape(K * N)
+    get_registry().inc("sched.lazy_pass", index="slots")
+    return None, sched.node_mask.reshape(T * M)
 
 
 # ---------------------------------------------------------------------------

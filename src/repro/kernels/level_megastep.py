@@ -533,7 +533,7 @@ def level_param_grads(kind: str, d_gates: Array, aux: Tuple[Array, ...],
                       weights: Tuple[Array, ...]) -> Tuple[Array, ...]:
     """Weight gradients from ONE flat batched pass over all slots
     (paper §3.5 lazy batching: the parameter-gradient operators run
-    once over ``T·M`` rows, not once per task).  Output order matches
+    once over every row of the batch, not once per task).  Output order matches
     ``GateSpec.weight_names``.
     """
     if kind == "lstm":

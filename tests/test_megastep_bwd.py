@@ -221,6 +221,134 @@ def test_bwd_parity_property(seed, kind, sizes):
 
 
 # ---------------------------------------------------------------------------
+# The lazy parameter pass: node table vs slot grid (``_lazy_index``)
+# ---------------------------------------------------------------------------
+
+def _lazy_pass_case(case, seed=4):
+    """Graphs, vertex and bucket pads where the node table is smaller
+    than the slot grid (``K*N < T*M``), and the schedule builder.
+
+    ``trees``: pow2-bucketed random binary trees, as the training cell
+    buckets them; ``dag``: multi-parent DAGs (duplicate child ids);
+    ``spliced``: the trees assembled by ``pipeline/splice.py`` from
+    solo schedules harvested off a cold pack, as the graph tier does.
+    """
+    from repro.pipeline import BucketPolicy
+    from repro.pipeline.splice import extract_solo, splice_schedules
+    rng = np.random.default_rng(seed)
+    if case == "dag":
+        fn = TreeLSTMVertex(input_dim=4, hidden=4, arity=3)
+        graphs = [random_dag(int(n), rng, max_arity=3) for n in (9, 12, 7)]
+    else:
+        fn = TreeLSTMVertex(input_dim=4, hidden=4, arity=2)
+        graphs = [random_binary_tree(int(n), rng)
+                  for n in rng.integers(2, 12, size=6)]
+    pads = BucketPolicy(mode="pow2").bucket(graphs)
+    pads = pads._replace(arity=fn.arity)
+
+    def build(nodes):
+        p = pads._replace(nodes=nodes)
+        if case != "spliced":
+            return pack_batch(graphs, *p)
+        cold = pack_batch(graphs[::-1], with_runs=False)
+        solos = [extract_solo(cold, len(graphs) - 1 - k)
+                 for k in range(len(graphs))]
+        return splice_schedules(graphs, solos, p)
+
+    return fn, graphs, pads, build
+
+
+@pytest.mark.parametrize("case,impl", [("trees", "chunked"),
+                                       ("trees", "pallas"),
+                                       ("dag", "chunked"),
+                                       ("spliced", "chunked")])
+def test_lazy_pass_node_table_parity(case, impl, monkeypatch):
+    """Where ``K*N < T*M`` the fused backward's lazy pass walks the node
+    table: parameter and external gradients match the op-by-op oracle
+    and the slot-grid pass, which the same graphs take once ``N`` is
+    padded so that the node table is no longer the smaller index."""
+    from repro.obs.registry import fresh_registry
+    fn, graphs, pads, build = _lazy_pass_case(case)
+    small = build(pads.nodes)
+    T, M, K = small.T, small.M, small.K
+    big = build(-(-T * M // K))
+    assert small.K * small.N < T * M <= big.K * big.N
+    if case == "dag":
+        cids = small.child_ids.reshape(T, -1)
+        assert any(len(np.unique(r[r != T * M])) < np.sum(r != T * M)
+                   for r in cids), "case must exercise duplicate child ids"
+    rng = np.random.default_rng(5)
+    inputs = [rng.standard_normal((g.num_nodes, 4)).astype(np.float32)
+              * 0.3 for g in graphs]
+    params = fn.init(jax.random.PRNGKey(5))
+
+    def grads(sched, mode):
+        ext = jnp.asarray(pack_external(inputs, sched, 4))
+        with fresh_registry() as reg:
+            g_p, g_e = _grads(fn, params, sched.to_device(), ext, mode,
+                              impl, monkeypatch)
+        g_e = np.asarray(g_e)
+        rows = [g_e[k * sched.N:k * sched.N + g.num_nodes]
+                for k, g in enumerate(graphs)]
+        return (g_p, rows), reg
+
+    g_oracle, _ = grads(small, "none")
+    g_nodes, reg_nodes = grads(small, "megastep")
+    g_slots, reg_slots = grads(big, "megastep")
+    assert reg_nodes.counter("sched.lazy_pass", index="nodes") == 1
+    assert reg_nodes.counter("sched.lazy_pass", index="slots") == 0
+    assert reg_slots.counter("sched.lazy_pass", index="slots") == 1
+    _assert_tree_close(g_oracle, g_nodes)
+    _assert_tree_close(g_slots, g_nodes)
+
+
+@pytest.mark.parametrize("graphs,shape,index", [
+    # ptb_train's buckets (T, M, K, N): 64 chains make K*N >= T*M ...
+    ("chains", (8, 64, 64, 16), "slots"),
+    ("chains", (16, 64, 64, 16), "slots"),
+    ("chains", (32, 64, 64, 32), "slots"),
+    ("chains", (64, 64, 64, 64), "slots"),
+    # ... but its epoch's tail batch of 20 chains does not.
+    ("chains", (8, 64, 20, 16), "nodes"),
+    ("vertices", (8, 64, 64, 16), "slots"),     # 1,024 > 512
+    ("trees", (8, 128, 16, 16), "nodes"),       # 16 trees of 8 leaves
+])
+def test_lazy_pass_index_follows_shapes(graphs, shape, index):
+    """The index is chosen from static shapes alone and counted once
+    per traced backward (``sched.lazy_pass{index}``): chains whose
+    node table is as large as the grid keep the slot grid, and so does
+    a batch of single vertices, whose node pad makes the table the
+    larger."""
+    from repro.obs.registry import fresh_registry
+    T, M, K, N = shape
+    rng = np.random.default_rng(T + K)
+    if graphs == "trees":
+        fn = TreeLSTMVertex(input_dim=4, hidden=4, arity=2)
+        gs = [random_binary_tree(8, rng) for _ in range(K)]
+    else:
+        fn = LSTMVertex(input_dim=4, hidden=4)
+        n = 1 if graphs == "vertices" else min(T, N)
+        gs = [chain(n)] + [chain(int(rng.integers(1, n + 1)))
+                           for _ in range(K - 1)]
+    sched = pack_batch(gs, pad_levels=T, pad_width=M, pad_arity=fn.arity,
+                       pad_nodes=N)
+    dev = sched.to_device()
+    ext = jnp.asarray(pack_external(
+        [np.zeros((g.num_nodes, 4), np.float32) for g in gs], sched, 4))
+    params = fn.init(jax.random.PRNGKey(0))
+
+    def loss(p, e):
+        buf = execute(fn, p, dev, e, fusion_mode="megastep").buf
+        return jnp.sum(readout_roots(buf, dev) ** 2)
+
+    with fresh_registry() as reg:
+        jax.eval_shape(jax.grad(loss, (0, 1)), params, ext)
+    other = {"nodes": "slots", "slots": "nodes"}[index]
+    assert reg.counter("sched.lazy_pass", index=index) == 1
+    assert reg.counter("sched.lazy_pass", index=other) == 0
+
+
+# ---------------------------------------------------------------------------
 # Analytic backward vs pure-autodiff oracle (one level, no scheduler)
 # ---------------------------------------------------------------------------
 

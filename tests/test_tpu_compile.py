@@ -222,3 +222,42 @@ def test_ssm_step_compiles(one_chip, monkeypatch):
     # the arena is updated in place, never copied
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_treelstm_train_step_compiles(one_chip, monkeypatch):
+    """The Tree-LSTM training step at paper widths (hidden 512, input
+    300) on a pow2 bucket of the training cell, ``(T, M, K, N)`` =
+    ``(16, 4096, 64, 128)``: both megasteps compile, and the lazy
+    parameter pass takes the node table (8,192 rows, not 65,536)."""
+    from repro.core.scheduler import execute, readout_roots
+    from repro.core.structure import DeviceSchedule
+    from repro.models.treelstm import TreeLSTMVertex
+    from repro.obs.registry import fresh_registry
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    T, M, K, N, A, X = 16, 4096, 64, 128, 2, 300
+    sd = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    i32 = jnp.int32
+    sched = DeviceSchedule(
+        child_ids=sd((T, M, A), i32), child_mask=sd((T, M, A)),
+        ext_ids=sd((T, M), i32), node_mask=sd((T, M)),
+        slot_of=sd((K, N), i32), node_valid=sd((K, N)),
+        root_slots=sd((K,), i32), sort_perm=sd((T, M * A), i32),
+        sorted_child_ids=sd((T, M * A), i32), run_head=sd((T, M * A), i32))
+    fn = TreeLSTMVertex(input_dim=X, hidden=H, arity=A)
+    params = jax.tree.map(lambda p: sd(p.shape, p.dtype),
+                          jax.eval_shape(fn.init, jax.random.PRNGKey(0)))
+
+    def loss(p, ext, dev, target):
+        buf = execute(fn, p, dev, ext, fusion_mode="megastep").buf
+        h = readout_roots(buf, dev)[:, -H:]
+        return jnp.mean((h - target) ** 2)
+
+    with fresh_registry() as reg:
+        compiled = jax.jit(jax.grad(loss)).lower(
+            params, sd((K * N + 1, X)), sched, sd((K, H))).compile()
+    assert reg.counter("sched.lazy_pass", index="nodes") == 1
+    assert reg.counter("sched.lazy_pass", index="slots") == 0
+    assert sorted(_kernel_names(compiled)) == ["_bwd_megastep_kernel",
+                                               "_megastep_kernel"]
