@@ -13,8 +13,8 @@ groups to batch within an epoch — the composer's wins on this corpus
 are depth-sorted bucket occupancy and deterministic epoch replay
 (from epoch 2 on, every batch is a schedule-cache hit).  On skewed
 corpora (repeated shapes — chains, serving traffic) it additionally
-manufactures WITHIN-epoch hits; `bench_graph_construction`'s
-`composer/*` rows measure that case.
+manufactures WITHIN-epoch hits; `tests/test_composer.py`
+(`test_composed_epoch_beats_fifo_on_skewed_corpus`) checks that case.
 
 Run:  PYTHONPATH=src python examples/treelstm_sentiment.py [--steps 150]
       (--no-compose falls back to FIFO epoch slicing)

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``benchmarks/run.py``,
+One rule for every entry point (``chip_smoke.py``, ``bench/run.py``,
 the examples): when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
 and this module sets nothing; otherwise the cache goes to one fixed
 directory inside the checkout, ``<checkout>/.jax_cache`` (git-ignored).

@@ -8,7 +8,7 @@
                     (SST-like random binary parses, ≤54 words).
 
 Each entry is a factory that builds the vertex function + matching data
-generator; the benchmarks and examples consume these.
+generator; ``chip_smoke.py``, the tests and the examples consume these.
 """
 
 from __future__ import annotations

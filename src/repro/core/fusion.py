@@ -5,7 +5,7 @@ generates fused elementwise kernels.  Under XLA, elementwise-chain fusion
 is performed by the compiler; what this module provides is
 
   1. the *verification* surface: count kernels (HLO fusions / loops) in
-     the compiled program of ``F`` so benchmarks can report the kernel
+     the compiled program of ``F`` so tests can check the kernel
      -launch reduction that Fig. 10 attributes to fusion, and
   2. the static *eager/lazy classification* of Proposition 2: given a
      closed-over jaxpr of ``F``, identify which equations depend on

@@ -37,21 +37,19 @@ apply → scatter as three XLA ops: scalar-prefetched ``child_ids``
 drive the gather DMA, the gate math stays VMEM-resident, and the
 contiguous block write aliases the buffer in place across the scan —
 no per-level HBM round-trip of the ``[M, A, S]`` child states or the
-``[M, G]`` gate lanes.  ``fusion_mode="auto"`` (default; overridable
-via the ``REPRO_FUSION`` env var) fuses whenever the cell supports it
-(including the fixed-arity check for Tree-FC's concat weight);
-``"none"`` keeps the op-by-op path (the correctness oracle and ablation
-baseline); ``"megastep"`` requires fusion and raises when unsupported.
-The fused path carries its own custom VJP, and its reverse sweep now
-mirrors the forward megastep: each reverse level is ONE fused op
-(``kops.bwd_megastep``) that recomputes the level's gates from the
-residual node buffer, applies the cotangent math for the declared
-kind, and scatter-ADDs the child-row cotangents into the carried
+``[M, G]`` gate lanes.  ``fusion_mode="auto"`` (default) fuses
+whenever the cell supports it (including the fixed-arity check for
+Tree-FC's concat weight); ``"none"`` keeps the op-by-op path (the
+correctness oracle); ``"megastep"`` requires fusion and raises when
+unsupported.  The fused path carries its own custom VJP, and its
+reverse sweep now mirrors the forward megastep: each reverse level is
+ONE fused op (``kops.bwd_megastep``) that recomputes the level's gates
+from the residual node buffer, applies the cotangent math for the
+declared kind, and scatter-ADDs the child-row cotangents into the carried
 gradient buffer (∂gather = scatter-add, §3.4) — on the pallas backend
 a single launch per level (``kernels/level_megastep_bwd.bwd_megastep``)
 with the gradient buffer aliased in place; off-pallas the jnp
-``level_bwd`` sweep, which stays the correctness oracle and ablation
-baseline (selectable via ``REPRO_FUSION=none`` /
+``level_bwd`` sweep, which stays the correctness oracle (selectable via
 ``REPRO_KERNEL_IMPL=chunked``).  The parameter/external gradients are
 computed lazily in one flat batched pass (§3.5) over the smaller of
 the node table and the slot grid — so both
@@ -66,7 +64,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
@@ -148,8 +145,6 @@ def _fusion_spec(fn: VertexFunction, fusion_mode: str, *, hoist: bool,
     packed schedule's ``A`` to match ``spec.arity`` exactly.
     """
     mode = fusion_mode
-    if mode == "auto":
-        mode = os.environ.get("REPRO_FUSION", "auto")
     if mode not in ("auto", "megastep", "none"):
         raise ValueError(f"fusion_mode must be 'auto', 'megastep' or "
                          f"'none', got {mode!r}")
@@ -602,8 +597,7 @@ def execute_serial(fn: VertexFunction, params: Params,
     paper compares against (no cross-sample batching, one kernel per
     vertex).  Returns, per sample, a ``[num_nodes, S]`` state matrix.
 
-    Used for correctness oracles and for the Fig. 8 serial-vs-batched
-    benchmarks.
+    A correctness oracle: the tests compare the batched paths with it.
     """
     results = []
     A = max(max(g.max_arity for g in graphs), 1,

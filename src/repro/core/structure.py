@@ -461,7 +461,7 @@ def _sorted_runs(child_ids: np.ndarray
 
 
 # ---------------------------------------------------------------------------
-# Bucketing (the dynamic-tensor memory plan ties into this; core/memory.py)
+# Bucketing
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)

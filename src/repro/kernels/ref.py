@@ -279,9 +279,9 @@ def megastep_cell_state(kind: str, child: jax.Array, rows: jax.Array,
         h_sum = jnp.sum(h_k, axis=1)
         xi, xf, xo, xu = jnp.split(rows, 4, axis=-1)
         bi, bf, bo, bu = jnp.split(b, 4)
-        # Per-child recurrence as a flattened [M*A, H] matmul: XLA CPU
-        # lowers the batched einsum form ~2.5x slower (measured; see
-        # docs/benchmarks.md "CPU fused Tree-LSTM" note).
+        # Per-child recurrence as a flattened [M*A, H] matmul: the
+        # batched einsum lowers ~2.5x slower on XLA CPU (1.06 vs
+        # 0.48 ms at M=460, A=2, H=64).
         rec_f = (h_k.reshape(M * A, H) @ uf).reshape(M, A, H)
         c, h = treelstm_gates(
             xi + h_sum @ ui + bi,

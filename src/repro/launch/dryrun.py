@@ -19,7 +19,7 @@ Per cell::
 
 plus the collective-byte parse of the optimized HLO
 (``analysis.hlo.collective_bytes``).  Results are appended to a JSONL
-file consumed by ``benchmarks/bench_roofline.py`` and EXPERIMENTS.md.
+file (``--out``, default ``results/dryrun.jsonl``).
 
 Usage::
 

@@ -24,9 +24,8 @@ the key as ``name{k=v,...}`` (sorted, prometheus-style).
 
 The process-global instance (:func:`get_registry`) is what the
 trainer's ``MetricLogger`` writes through to, what pipelines and
-engines register into, and what ``benchmarks/run.py`` reads the
-per-stage breakdown rows from.  Tests and benches can swap a fresh one
-in with :func:`fresh_registry`.
+engines register into.  Tests and benches can swap a fresh one in
+with :func:`fresh_registry`.
 """
 
 from __future__ import annotations

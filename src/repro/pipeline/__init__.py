@@ -4,8 +4,7 @@ architecture note)."""
 
 from repro.pipeline.buckets import (BucketPolicy, PadDims, ShapeCensus,
                                     TIGHT, tight_dims)
-from repro.pipeline.cache import (ScheduleCache, cache_enabled_default,
-                                  splice_enabled_default)
+from repro.pipeline.cache import ScheduleCache
 from repro.pipeline.composer import (BatchComposer, ComposedBatch,
                                      CompositionStats,
                                      ShardedCompositionStats, ShardedStep,
@@ -24,8 +23,7 @@ __all__ = [
     "CompositionStats", "PackedBatch", "PadDims", "SCHEMA_VERSION",
     "ScheduleCache", "SchedulePersist", "SchedulePipeline",
     "ShardedCompositionStats", "ShardedPipeline", "ShardedStep",
-    "ShapeCensus", "TIGHT", "batch_fingerprint", "cache_enabled_default",
-    "extract_solo", "fifo_stats", "graph_fingerprint",
-    "graph_schedule_key", "persist_dir_default", "splice_enabled_default",
-    "splice_schedules", "tight_dims",
+    "ShapeCensus", "TIGHT", "batch_fingerprint", "extract_solo",
+    "fifo_stats", "graph_fingerprint", "graph_schedule_key",
+    "persist_dir_default", "splice_schedules", "tight_dims",
 ]

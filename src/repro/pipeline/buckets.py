@@ -16,7 +16,7 @@ for serving and streaming training.
 
 :class:`ShapeCensus` is the proof: it counts distinct padded shape
 tuples actually produced (each distinct tuple = one XLA compilation of
-the level scan), the compile-count metric the benchmarks report.
+the level scan).
 """
 
 from __future__ import annotations

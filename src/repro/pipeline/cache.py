@@ -47,18 +47,11 @@ rests on the pack-order invariant documented in
 :mod:`repro.pipeline.splice` and on frozen topologies
 (:func:`~repro.pipeline.fingerprint.graph_fingerprint` freezes a graph
 at first fingerprint, so a graph-tier key can never go stale).
-
-Set ``REPRO_SCHED_CACHE=0`` to disable caching globally (every lookup
-cold-packs and the disk and graph tiers are bypassed — the
-ablation/debug setting, exercised as a CI leg).  Set
-``REPRO_SCHED_SPLICE=0`` to keep the batch/disk tiers but disable the
-graph tier (splice ablation, also a CI leg).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 from collections import OrderedDict
 from pathlib import Path
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
@@ -74,16 +67,6 @@ from repro.pipeline.splice import extract_solo, splice_schedules
 Pads = Tuple[Optional[int], Optional[int], Optional[int], Optional[int]]
 
 _TIGHT_PADS: Pads = (None, None, None, None)
-
-
-def cache_enabled_default() -> bool:
-    """The ``REPRO_SCHED_CACHE`` env gate (unset / "1" = on)."""
-    return os.environ.get("REPRO_SCHED_CACHE", "1") != "0"
-
-
-def splice_enabled_default() -> bool:
-    """The ``REPRO_SCHED_SPLICE`` env gate (unset / "1" = on)."""
-    return os.environ.get("REPRO_SCHED_SPLICE", "1") != "0"
 
 
 @dataclasses.dataclass
@@ -105,14 +88,13 @@ class ScheduleCache:
     """Three-tier (batch LRU + per-graph tier + optional disk) cache
     over packed schedules, keyed by batch topology fingerprint.
 
-    ``enabled=None`` (default) reads ``REPRO_SCHED_CACHE`` at
-    construction; ``False`` forces every lookup to cold-pack (stats
-    still count misses, so instrumented code behaves identically).
+    ``enabled=False`` forces every lookup to cold-pack and bypasses the
+    disk and graph tiers (stats still count misses, so instrumented
+    code behaves identically).
 
-    ``splice=None`` (default) reads ``REPRO_SCHED_SPLICE`` at
-    construction; ``False`` turns the per-graph tier off (no harvest,
-    no splice, graph lookups still work but cold-pack through the
-    graph counters).
+    ``splice=False`` turns the per-graph tier off (no harvest, no
+    splice, graph lookups still work but cold-pack through the graph
+    counters).
 
     ``persist=None`` (default) reads ``REPRO_SCHED_PERSIST`` at
     construction; pass a directory path or a :class:`SchedulePersist`
@@ -121,21 +103,19 @@ class ScheduleCache:
     """
 
     def __init__(self, capacity: int = 128,
-                 enabled: Optional[bool] = None,
+                 enabled: bool = True,
                  persist: Union[SchedulePersist, str, Path, bool,
                                 None] = None,
                  graph_capacity: int = 1024,
-                 splice: Optional[bool] = None) -> None:
+                 splice: bool = True) -> None:
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         if graph_capacity < 1:
             raise ValueError("graph_capacity must be >= 1")
         self.capacity = capacity
         self.graph_capacity = graph_capacity
-        self.enabled = (cache_enabled_default()
-                        if enabled is None else bool(enabled))
-        self.splice = (splice_enabled_default()
-                       if splice is None else bool(splice))
+        self.enabled = bool(enabled)
+        self.splice = bool(splice)
         if persist is None or persist is True:
             # True = "enable from the environment" (same as the default)
             pdir = persist_dir_default()
@@ -161,8 +141,8 @@ class ScheduleCache:
         # Holds (key-or-None, graphs, pads, entry): the ENTRY reference
         # pins it against eviction, and the (graphs, pads) identity
         # match keeps the pairing sound when the cache is disabled
-        # (key is None there — the old key-only pending never engaged,
-        # so the ablation leg packed every pair twice).
+        # (key is None there — a key-only pending would never engage,
+        # and a disabled cache would pack every pair twice).
         self._pending: Optional[Tuple[Optional[bytes],
                                       Tuple[InputGraph, ...], Pads,
                                       _Entry]] = None

@@ -12,12 +12,11 @@ that wins that cost back:
      become cache keys;
   2. **cache** (``cache.py``) — LRU from fingerprint to packed
      ``LevelSchedule`` + its device twin: a hit skips ``pack_batch``
-     AND the host→device transfer (``REPRO_SCHED_CACHE=0`` disables).
+     AND the host→device transfer.
      Below the batch LRU sits the per-GRAPH tier (``splice.py``):
      cold packs harvest their members' solo schedules, and a batch
      miss whose members have all been seen is SPLICED host-side —
-     byte-identical to the cold pack, no topology walk
-     (``REPRO_SCHED_SPLICE=0`` disables just this tier);
+     byte-identical to the cold pack, no topology walk;
   3. **bucket** (``buckets.py``) — pad dims quantized to bucket
      boundaries, so one compiled megastep program serves many
      minibatches (``ShapeCensus`` counts the compiles to prove it);
@@ -79,22 +78,19 @@ class SchedulePipeline:
 
     ``bucket_policy`` defaults to :class:`BucketPolicy`'s multiples-of-8
     ladder; pass ``bucket_policy=None`` for tight packing (every new
-    shape recompiles — the ablation baseline).  ``cache`` defaults to a
-    fresh :class:`ScheduleCache` honouring ``REPRO_SCHED_CACHE`` and
-    ``REPRO_SCHED_SPLICE``; ``splice`` pins the per-graph tier on/off
-    for the default cache (ignored when ``cache`` is passed).
+    shape recompiles).  ``cache`` defaults to a fresh
+    :class:`ScheduleCache`.
     """
 
     def __init__(self, ext_dim: int, *,
                  bucket_policy: Optional[BucketPolicy] = BucketPolicy(),
                  cache: Optional[ScheduleCache] = None,
                  cache_capacity: int = 128,
-                 with_runs: bool = True,
-                 splice: Optional[bool] = None):
+                 with_runs: bool = True):
         self.ext_dim = ext_dim
         self.bucket_policy = bucket_policy
         self.cache = cache if cache is not None \
-            else ScheduleCache(capacity=cache_capacity, splice=splice)
+            else ScheduleCache(capacity=cache_capacity)
         self.census = ShapeCensus()
         #: False for forward-only pipelines (serving): schedules are
         #: packed WITHOUT the backward's sorted-run arrays, so the LRU
@@ -226,8 +222,7 @@ class ShardedPipeline:
     def __init__(self, ext_dim: int, num_shards: int, *,
                  bucket_policy: Optional[BucketPolicy] = BucketPolicy(),
                  cache_capacity: int = 128,
-                 with_runs: bool = True,
-                 splice: Optional[bool] = None):
+                 with_runs: bool = True):
         if num_shards < 1:
             raise ValueError("num_shards must be >= 1")
         self.ext_dim = ext_dim
@@ -235,7 +230,7 @@ class ShardedPipeline:
         self.bucket_policy = bucket_policy
         self.pipes = [SchedulePipeline(ext_dim, bucket_policy=bucket_policy,
                                        cache_capacity=cache_capacity,
-                                       with_runs=with_runs, splice=splice)
+                                       with_runs=with_runs)
                       for _ in range(num_shards)]
         get_registry().register_provider("sharded_pipeline", self.stats)
 

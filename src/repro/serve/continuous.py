@@ -41,9 +41,10 @@ arxiv 1701.03980) executed through the fused megastep:
     requests interleave).
 
 **Bit-identity contract** (the property the test suite proves on both
-``REPRO_FUSION`` legs): every request's root state — and its readout
-logits — is bit-identical to scoring that request ALONE through
-``StructureServeEngine``.  This holds because (a) the per-row math of
+fusion legs, ``fusion_mode="none"`` and the megastep): every request's
+root state — and its readout logits — is bit-identical to scoring that
+request ALONE through ``StructureServeEngine``.  This holds because (a)
+the per-row math of
 ``frontier_step`` is exactly the level scan's on the matching fusion
 leg, (b) inputs are projected at admission over the same padded
 ``[N + 1, X]`` matrix solo scoring projects, and (c) XLA's row-wise

@@ -368,10 +368,9 @@ class VertexServeEngine(_EngineBase):
     mask, so admission/retirement is pure data.
 
     ``fusion_mode`` is resolved exactly like the scheduler's
-    (:func:`repro.core.scheduler.resolve_fusion`, including the
-    ``REPRO_FUSION`` env override): when the cell declares a
-    :class:`~repro.core.vertex.GateSpec`, the tick is ONE fused
-    megastep launch; ``"none"`` keeps the op-by-op oracle tick.
+    (:func:`repro.core.scheduler.resolve_fusion`): when the cell
+    declares a :class:`~repro.core.vertex.GateSpec`, the tick is ONE
+    fused megastep launch; ``"none"`` keeps the op-by-op oracle tick.
     """
 
     def __init__(self, fn, params: Params, *, num_slots: int,

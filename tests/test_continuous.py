@@ -307,14 +307,66 @@ def test_token_generation_deterministic_across_interleavings():
     assert alone == crowded and len(alone) == 6
 
 
+def test_poisson_trace_replay_is_bit_identical_to_solo():
+    """Serving parity under load: 120 chain requests (4-32 tokens) on a
+    seeded Poisson trace at 250 req/s, replayed in real time into a
+    warmed engine, each finish with a root state bitwise equal to
+    scoring the request alone."""
+    import time
+
+    lengths = (4, 6, 8, 12, 16, 24, 32)
+    input_dim = 32
+    fn = LSTMVertex(input_dim=input_dim, hidden=64)
+    params = fn.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    arrivals = np.cumsum(rng.exponential(1.0 / 250.0, size=120))
+    lens = rng.choice(np.asarray(lengths), size=120)
+    eng = ContinuousBatchEngine(
+        fn, params, num_rows=1024, frontier_width=64, fusion_mode="auto",
+        policy=AdmissionPolicy(min_occupancy=0.0, max_window=8))
+    warm_rng = np.random.default_rng(99)
+    for j, n in enumerate(lengths):       # compile-warm preamble
+        eng.submit(ContinuousRequest(
+            1000 + j, chain(n), warm_rng.standard_normal(
+                (n, input_dim)).astype(np.float32)))
+    eng.run()
+    x_rng = np.random.default_rng(1)
+    reqs = [ContinuousRequest(
+                i, chain(int(n)),
+                x_rng.standard_normal((int(n), input_dim))
+                .astype(np.float32) * 0.3)
+            for i, n in enumerate(lens)]
+    t0, i = time.monotonic(), 0
+    while True:
+        now = time.monotonic() - t0
+        while i < len(reqs) and arrivals[i] <= now:
+            eng.submit(reqs[i])
+            i += 1
+        live = eng.step()
+        if i >= len(reqs) and live == 0:
+            break
+        if live == 0:
+            time.sleep(min(0.001, max(0.0, arrivals[i]
+                                      - (time.monotonic() - t0))))
+        assert time.monotonic() - t0 < 300.0, "replay over its wall budget"
+    assert all(r.status == "ok" for r in reqs)
+    solo = StructureServeEngine(fn, params, batch_size=1, compose=False,
+                                fusion_mode="auto")
+    checks = [StructureRequest(r.request_id, r.graph, r.inputs)
+              for r in reqs]
+    for c in checks:
+        assert solo.submit(c), c.error
+    solo.run()
+    for r, c in zip(reqs, checks):
+        assert c.status == "ok", (c.status, c.error)
+        assert np.array_equal(r.root_state, c.root_state), r.request_id
+
+
 def test_plan_and_schedule_reuse_on_admission():
     """Recurring topologies admit through the cache's per-GRAPH tier —
     the pipeline satellite: admission does zero packing work on a hit,
     and the frontier plan memoized in the tier entry's extras rides
-    along (plan lifetime == schedule lifetime, no private LRU).  The
-    cache is pinned ON so the contract holds under the
-    REPRO_SCHED_CACHE=0 CI leg too (where the ablation legitimately
-    re-packs and re-plans every admission)."""
+    along (plan lifetime == schedule lifetime, no private LRU)."""
     fn, params = _LSTM, _LSTM_PARAMS
     rng = np.random.default_rng(3)
     eng = ContinuousBatchEngine(fn, params, num_rows=64, frontier_width=4,
@@ -332,9 +384,9 @@ def test_plan_and_schedule_reuse_on_admission():
 
 
 def test_disabled_cache_admission_replans_every_request():
-    """The REPRO_SCHED_CACHE=0 ablation really is uncached at
-    admission: every submit re-packs and re-plans (one solo
-    ``pack_batch`` each — never two), and serving still works."""
+    """A disabled cache really is uncached at admission: every submit
+    re-packs and re-plans (one solo ``pack_batch`` each — never two),
+    and serving still works."""
     fn, params = _LSTM, _LSTM_PARAMS
     rng = np.random.default_rng(4)
     eng = ContinuousBatchEngine(fn, params, num_rows=64, frontier_width=4,

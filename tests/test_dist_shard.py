@@ -1,4 +1,4 @@
-"""The sharded megastep training path (tier1-dist suite).
+"""The sharded megastep training path.
 
 Runs under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` via
 fresh-interpreter subprocesses (jax locks the device count at backend
@@ -19,8 +19,7 @@ mesh:
       residual in ``TrainState.ef``, and a ``plan_downsize``-driven
       8→4 restart restores from checkpoint and keeps training.
 
-``REPRO_FUSION`` is inherited by the subprocesses, so the tier1-dist
-CI job sweeps the fused and unfused legs with the same tests.
+Each test runs on both fusion legs (``fusion_mode`` "auto" and "none").
 """
 
 import pytest
@@ -28,7 +27,7 @@ import pytest
 from tests.util_subproc import run_with_devices
 
 _PRELUDE = """
-import os, numpy as np, jax, jax.numpy as jnp
+import numpy as np, jax, jax.numpy as jnp
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.core.scheduler import execute, readout_roots
@@ -38,7 +37,6 @@ from repro.models.treelstm import TreeLSTMVertex
 from repro.pipeline import SchedulePipeline, ShardedPipeline
 from repro.train import MetricLogger, TrainConfig, Trainer
 
-FUSION = os.environ.get("REPRO_FUSION", "auto")
 IN_DIM, HID = 8, 4
 fn = TreeLSTMVertex(input_dim=IN_DIM, hidden=HID, arity=2)
 
@@ -73,12 +71,20 @@ def epochs_of(n):
 """
 
 
+FUSIONS = ["auto", "none"]
+
+
+def _prelude(fusion: str) -> str:
+    return f"FUSION = {fusion!r}\n" + _PRELUDE
+
+
 @pytest.mark.slow
-def test_sharded_step_matches_single_replica_baseline():
+@pytest.mark.parametrize("fusion", FUSIONS)
+def test_sharded_step_matches_single_replica_baseline(fusion):
     """Criteria (a) + (b) in one interpreter: trainer-level loss
     parity over 2 composed epochs, then per-replica bit-identity of
     pre-reduction grads and per-sample losses against solo packs."""
-    run_with_devices(_PRELUDE + """
+    run_with_devices(_prelude(fusion) + """
 R, BS, STEPS = 8, 16, 8
 mesh = remesh(jax.devices(), {"data": R})
 
@@ -174,11 +180,12 @@ print("per-sample parity OK")
 
 
 @pytest.mark.slow
-def test_ef_on_mesh_and_elastic_8_to_4_restart():
+@pytest.mark.parametrize("fusion", FUSIONS)
+def test_ef_on_mesh_and_elastic_8_to_4_restart(fusion):
     """compress_grads on the mesh carries a live per-replica residual
     in TrainState.ef, and a plan_downsize-driven 8→4 restart restores
     the checkpoint onto the smaller mesh and keeps training."""
-    run_with_devices(_PRELUDE + """
+    run_with_devices(_prelude(fusion) + """
 import tempfile
 ckpt_dir = tempfile.mkdtemp()
 R, BS = 8, 16

@@ -410,14 +410,16 @@ def test_scheduler_pallas_megastep_matches_unfused(monkeypatch):
 # fusion_mode plumbing
 # ---------------------------------------------------------------------------
 
-def test_fusion_mode_auto_uses_megastep_and_env_disables(monkeypatch):
+def test_fusion_mode_auto_uses_megastep_and_env_disables():
+    from repro.core.scheduler import resolve_fusion
     fn, params, _, _, sched, ext = _case("lstm", 13)
+    assert resolve_fusion(fn, "auto") is not None           # megastep
+    assert resolve_fusion(fn, "none") is None               # op-by-op
     dev = sched.to_device()
     r_auto = execute(fn, params, dev, ext)                  # default: auto
-    monkeypatch.setenv("REPRO_FUSION", "none")
-    r_env_off = execute(fn, params, dev, ext)
+    r_off = execute(fn, params, dev, ext, fusion_mode="none")
     np.testing.assert_allclose(np.asarray(r_auto.buf),
-                               np.asarray(r_env_off.buf),
+                               np.asarray(r_off.buf),
                                rtol=1e-5, atol=1e-6)
 
 
@@ -441,12 +443,11 @@ def test_fusion_mode_megastep_requires_gate_spec():
                 jnp.zeros((4, 2)), hoist=False, fusion_mode="megastep")
 
 
-def test_fusion_mode_treefc_arity_mismatch(monkeypatch):
+def test_fusion_mode_treefc_arity_mismatch():
     """Tree-FC's concat weight fixes the gather arity: a schedule packed
     at a different A must raise under "megastep" and resolve to the
     op-by-op path (spec None) under "auto"."""
     from repro.core.scheduler import resolve_fusion
-    monkeypatch.delenv("REPRO_FUSION", raising=False)   # CI matrix sets it
     fn = TreeFCVertex(input_dim=2, hidden=3)          # arity 2
     params = fn.init(jax.random.PRNGKey(0))
     sched = pack_batch([chain(3)])                    # chains pack at A=1

@@ -158,17 +158,30 @@ def test_cache_lru_eviction():
     assert cache.misses == 4
 
 
-def test_cache_env_gate_disables(monkeypatch):
+def test_second_epoch_over_identical_corpus_hits_90_percent():
+    """Cache effectiveness: a fresh cache over 24 batches of 16 trees
+    serves the second epoch of the same corpus at >= 90% hits."""
+    rng = np.random.default_rng(0)
+    corpus = [[random_binary_tree(int(rng.integers(4, 24)), rng)
+               for _ in range(16)] for _ in range(24)]
+    cache = ScheduleCache(enabled=True, persist=False)
+    for graphs in corpus:
+        cache.get_or_pack(graphs)
+    cache.reset_stats()
+    for graphs in corpus:                 # the second epoch, isolated
+        cache.get_or_pack(graphs)
+    assert cache.hit_rate >= 0.9, cache.stats()
+
+
+def test_cache_env_gate_disables():
     graphs, _ = _forest(5)
-    monkeypatch.setenv("REPRO_SCHED_CACHE", "0")
-    cache = ScheduleCache()              # reads the env at construction
+    cache = ScheduleCache(enabled=False)
     assert not cache.enabled
     s1 = cache.get_or_pack(graphs)
     s2 = cache.get_or_pack(graphs)
     assert s1 is not s2                  # every lookup cold-packs
     assert cache.hits == 0 and cache.misses == 2 and len(cache) == 0
-    monkeypatch.setenv("REPRO_SCHED_CACHE", "1")
-    assert ScheduleCache().enabled
+    assert ScheduleCache().enabled       # on by default
 
 
 def _counting_pack(monkeypatch):
@@ -189,8 +202,8 @@ def test_cache_disabled_pair_executes_one_pack(monkeypatch):
     """Regression: with the cache DISABLED, a ``get_or_pack`` →
     ``get_or_pack_device`` pair used to run ``pack_batch`` TWICE and
     count two misses/packs (the disabled leg returned ``key=None``, so
-    the pending-attach dedupe never engaged) — the ablation CI leg did
-    2x pack work per step.  One logical lookup = one pack = one miss,
+    the pending-attach dedupe never engaged) — 2x pack work per step.
+    One logical lookup = one pack = one miss,
     enabled or not."""
     calls = _counting_pack(monkeypatch)
     graphs, _ = _forest(7)
@@ -421,7 +434,6 @@ def test_pipeline_parity_bit_identical(mode, impl, monkeypatch):
                                 impl, monkeypatch)
 
     # -- cache hit (tight pads): bit-identical to the fresh tight pack --
-    # (cache pinned ON so the test holds under the REPRO_SCHED_CACHE=0 leg)
     pipe_tight = SchedulePipeline(INPUT_DIM, bucket_policy=None,
                                   cache=ScheduleCache(enabled=True))
     pipe_tight.pack(graphs, inputs)      # cold
@@ -885,6 +897,33 @@ def test_sharded_pipeline_epoch2_hit_rate_matches_unsharded():
         d_miss = s["misses"] - snaps[r]["misses"]
         assert d_hits + d_miss > 0
         assert d_hits / (d_hits + d_miss) == u_rate, (r, d_hits, d_miss)
+
+
+def test_sharded_epoch2_balance_and_hits_on_eight_replicas():
+    """256 trees of 2-31 nodes, steps of 32 over 8 replicas: replica
+    node imbalance <= 1.15, and every replica's second-epoch cache hit
+    rate >= 0.99."""
+    from repro.pipeline import ShardedPipeline
+
+    rng = np.random.default_rng(0)
+    graphs = [random_binary_tree(int(rng.integers(2, 32)), rng)
+              for _ in range(256)]
+    inputs = [rng.standard_normal((g.num_nodes, 8)).astype(np.float32)
+              * 0.3 for g in graphs]
+    sp = ShardedPipeline(8, 8)
+    comp = sp.composer(32)
+    steps, stats = comp.compose_sharded(graphs, inputs, num_shards=8)
+    assert stats.node_imbalance <= 1.15, stats.replica_nodes
+    for st in steps:
+        sp.pack_step(st)
+    snaps = [dict(p.cache.stats()) for p in sp.pipes]
+    for st in comp.compose_sharded(graphs, inputs, num_shards=8)[0]:
+        sp.pack_step(st)
+    for r, p in enumerate(sp.pipes):
+        s = p.cache.stats()
+        hits = s["hits"] - snaps[r]["hits"]
+        total = hits + s["misses"] - snaps[r]["misses"]
+        assert total and hits / total >= 0.99, (r, hits, total)
 
 
 def test_sharded_pipeline_validates():

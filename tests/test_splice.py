@@ -20,7 +20,7 @@ from repro.core.structure import (InputGraph, balanced_binary_tree, chain,
                                   random_binary_tree, random_dag)
 from repro.models.treelstm import TreeLSTMVertex
 from repro.pipeline import (ScheduleCache, extract_solo, graph_fingerprint,
-                            splice_enabled_default, splice_schedules)
+                            splice_schedules)
 from repro.serve import ContinuousBatchEngine, ContinuousRequest
 from repro.train import MetricLogger, TrainConfig, Trainer
 
@@ -192,20 +192,43 @@ def test_cache_graph_tier_repads_solo_from_tight_entry():
     _assert_sched_equal(solo_r, pack_batch([g], *pads))
 
 
-def test_splice_env_gate_disables_graph_tier(monkeypatch):
-    monkeypatch.setenv("REPRO_SCHED_SPLICE", "0")
-    assert not splice_enabled_default()
+def test_every_unseen_zipf_combination_splices_without_packing():
+    """On a Zipf-weighted corpus (24 trees of 32-128 nodes, 16 unseen
+    batch combinations of 16), a cache whose graph tier holds every
+    topology assembles every combination by splicing: zero
+    ``pack_batch`` calls."""
+    rng = np.random.default_rng(0)
+    n_topologies, bs, n_combos = 24, 16, 16
+    topos = [random_binary_tree(int(rng.integers(32, 128)), rng)
+             for _ in range(n_topologies)]
+    zipf = 1.0 / np.arange(1, n_topologies + 1) ** 1.2
+    zipf /= zipf.sum()
+    combos, seen = [], set()
+    while len(combos) < n_combos:
+        idx = tuple(int(i) for i in rng.choice(n_topologies, bs, p=zipf))
+        if idx not in seen:
+            seen.add(idx)
+            combos.append([topos[i] for i in idx])
+    cache = ScheduleCache(enabled=True, persist=False)
+    for g in topos:
+        cache.get_or_pack([g], with_runs=False)
+    cache.reset_stats()
+    for c in combos:
+        cache.get_or_pack(c, with_runs=False)
+    s = cache.stats()
+    assert s["splices"] == n_combos and s["packs"] == 0, s
+
+
+def test_splice_env_gate_disables_graph_tier():
     rng = np.random.default_rng(10)
     graphs = _forest(rng, 3)
-    cache = ScheduleCache(enabled=True, persist=False)
+    cache = ScheduleCache(enabled=True, persist=False, splice=False)
     assert not cache.splice
     cache.get_or_pack(graphs[:2])
     assert cache.harvests == 0
     cache.get_or_pack([graphs[1], graphs[0]])
     assert cache.splices == 0 and cache.packs == 2     # plain cold pack
-    monkeypatch.setenv("REPRO_SCHED_SPLICE", "1")
-    assert splice_enabled_default()
-    assert ScheduleCache(enabled=True, persist=False).splice
+    assert ScheduleCache(enabled=True, persist=False).splice   # default
 
 
 def test_warm_restart_splices_from_per_graph_disk_entries(tmp_path):

@@ -83,16 +83,14 @@ def test_decode_staggered_admission_slot_isolation():
     np.testing.assert_array_equal(a[0], b[0])
 
 
-def test_decode_respects_fusion_env(monkeypatch):
-    """REPRO_FUSION=none must force the op-by-op tick under "auto" —
-    the same env contract as the training scheduler."""
+def test_decode_respects_fusion_env():
+    """``fusion_mode="none"`` forces the op-by-op tick, while "auto"
+    fuses — the same contract as the training scheduler."""
     fn = GRUVertex(input_dim=4, hidden=3)
     params = fn.init(jax.random.PRNGKey(2))
-    monkeypatch.delenv("REPRO_FUSION", raising=False)   # CI matrix sets it
     eng_auto = VertexServeEngine(fn, params, num_slots=2)
     assert eng_auto.fused
-    monkeypatch.setenv("REPRO_FUSION", "none")
-    eng_off = VertexServeEngine(fn, params, num_slots=2)
+    eng_off = VertexServeEngine(fn, params, num_slots=2, fusion_mode="none")
     assert not eng_off.fused
 
 
